@@ -37,11 +37,9 @@ from cook_tpu.analysis.core import Finding, ModuleInfo
 _FACTORIES = ("counter", "gauge", "meter", "timer", "histogram")
 
 # metric value classes that must come from a registry; matched on the
-# resolved dotted import (both the labeled registry and the legacy
-# utils.metrics classes)
+# resolved dotted import
 _METRIC_CLASSES = frozenset(
-    f"{mod}.{cls}"
-    for mod in ("cook_tpu.obs.metrics", "cook_tpu.utils.metrics")
+    f"cook_tpu.obs.metrics.{cls}"
     for cls in ("Counter", "Gauge", "Meter", "Histogram", "Timer"))
 
 # label keys that carry per-job/per-task identity — unbounded
@@ -99,10 +97,9 @@ def check(mod: ModuleInfo) -> list[Finding]:
         for child in ast.iter_child_nodes(parent):
             parents[child] = parent
 
-    # registry modules themselves construct the value classes
+    # the registry module itself constructs the value classes
     norm = mod.path.replace("\\", "/")
-    is_registry_module = norm.endswith(
-        ("obs/metrics.py", "utils/metrics.py"))
+    is_registry_module = norm.endswith("obs/metrics.py")
 
     for node in ast.walk(mod.tree):
         if not isinstance(node, ast.Call):

@@ -16,7 +16,9 @@ from cook_tpu.obs.decisions import DecisionBook
 from cook_tpu.obs.export import SpanJsonlExporter, to_chrome_trace
 from cook_tpu.obs.metrics import Registry
 from cook_tpu.obs.metrics import registry as metrics_registry
-from cook_tpu.obs.profiler import CycleProfiler, CycleRec, profiler
+from cook_tpu.obs.profiler import (CycleProfiler, CycleRec, annotation,
+                                   current_record, launch_span_id,
+                                   profiler)
 from cook_tpu.obs.trace import (NOOP_SPAN, Span, Tracer, assemble_tree,
                                 make_traceparent, new_span_id,
                                 new_trace_id, now_ms, parse_traceparent,
@@ -24,8 +26,8 @@ from cook_tpu.obs.trace import (NOOP_SPAN, Span, Tracer, assemble_tree,
 
 __all__ = [
     "CycleProfiler", "CycleRec", "DecisionBook", "NOOP_SPAN", "Registry",
-    "Span", "SpanJsonlExporter", "Tracer", "assemble_tree",
-    "make_traceparent", "metrics_registry", "new_span_id", "new_trace_id",
-    "now_ms", "parse_traceparent", "profiler", "to_chrome_trace",
-    "tracer",
+    "Span", "SpanJsonlExporter", "Tracer", "annotation", "assemble_tree",
+    "current_record", "launch_span_id", "make_traceparent",
+    "metrics_registry", "new_span_id", "new_trace_id", "now_ms",
+    "parse_traceparent", "profiler", "to_chrome_trace", "tracer",
 ]

@@ -1,9 +1,9 @@
 """Process-wide metrics registry: one exposition path for everything.
 
 The scheduler grew two generations of telemetry — codahale-style
-dotted-name Meters/Timers (utils/metrics.py, reporter.clj lineage) and
-ad-hoc `self.metrics` dicts — each rendered by its own code.  This
-module is the single registry both generations now live in:
+dotted-name Meters/Timers (reporter.clj lineage) and ad-hoc
+`self.metrics` dicts — each rendered by its own code.  This module is
+the single registry both generations now live in:
 
 * **New API** — snake_case metric families with bounded label sets:
   ``registry.counter("match_matched_total", pool="default").inc(n)``,
@@ -26,8 +26,8 @@ of an unbounded scrape.
 flatten (labeled children use Graphite 1.1 ``;k=v`` tag syntax), and
 ``render()`` is the one Prometheus text-exposition path `/metrics`
 serves.  Deliberately dependency-free: stdlib only, no cook_tpu
-imports (utils.metrics aliases its module-global registry to this one,
-so importing from here must never import back).
+imports (utils.metrics re-exports the module-global registry and
+holds the reporters, so importing from here must never import back).
 """
 from __future__ import annotations
 
@@ -55,8 +55,8 @@ def _fmt(v: float) -> str:
 
 
 def _prom_name(name: str) -> str:
-    # identical sanitation to utils.metrics._prom_name so migrated
-    # dotted names keep their historical exposition names
+    # the historical sanitation, so migrated dotted names keep their
+    # exposition names
     out = []
     for ch in name:
         out.append(ch if ch.isalnum() or ch == "_" else "_")
@@ -497,6 +497,6 @@ class Registry:
             self._families.clear()
 
 
-# the process-wide default registry; utils.metrics aliases its module
-# global to this exact instance so both generations share exposition
+# the process-wide default registry (utils.metrics re-exports it for
+# legacy imports)
 registry = Registry()

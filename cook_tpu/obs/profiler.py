@@ -19,7 +19,18 @@ to run enabled on every cycle:
 
 Every record carries wall AND ``thread_time`` CPU per phase, so a
 phase that is long but idle (blocked on the device, on a queue, on
-fsync) is distinguishable from one burning the cycle thread.
+fsync) is distinguishable from one burning the cycle thread.  Waits
+measured where they happen (the launch transaction's shard-lock and
+group-commit waits) ride along as child spans of the phase that was
+open (``CycleRec.child``, reached from the store through the record
+the thread exposes, :func:`current_record`).
+
+The device trace's clock: while a JAX profiler session is recording,
+each segment between two boundaries is also a host-plane event named
+after the record's kind with its phase and cycle number as stats
+(:func:`annotation`), so a device trace can be read against the
+cycle's phases without any wall-clock mapping.  With no session the
+cost is one ``is_enabled()`` check per boundary.
 
 Served by ``GET /debug/profile``; the K worst cycles export as
 Chrome-trace/Perfetto JSON via :meth:`CycleProfiler.chrome_trace`.
@@ -27,6 +38,8 @@ Chrome-trace/Perfetto JSON via :meth:`CycleProfiler.chrome_trace`.
 from __future__ import annotations
 
 import collections
+import hashlib
+import sys
 import threading
 import time
 from typing import Optional
@@ -41,6 +54,66 @@ OVERLAP_PHASES = frozenset({"consume", "queue_wait"})
 
 # log2 bucket bounds in ms: ~15.6 us .. ~16.4 s
 _BUCKET_MS = tuple(2.0 ** i for i in range(-6, 15))
+
+# jax.profiler.TraceAnnotation once jax is loaded (obs itself never
+# imports jax: the agent and the CLI run without it)
+_TA = None
+
+
+def _trace_annotation():
+    global _TA
+    if _TA is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _TA = TraceAnnotation
+    return _TA
+
+
+class _NoAnnotation:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        pass
+
+
+_NO_ANNOTATION = _NoAnnotation()
+
+
+def annotation(name: str, **stats):
+    """A host-plane trace event ``name`` (``stats`` attached) while a
+    JAX profiler session is recording, else a shared no-op context:
+    one ``is_enabled()`` check."""
+    ta = _TA or _trace_annotation()
+    if ta is not None and ta.is_enabled():
+        return ta(name, **stats)
+    return _NO_ANNOTATION
+
+
+_local = threading.local()
+
+
+def current_record() -> Optional["CycleRec"]:
+    """The cycle record this thread exposes (``with rec.exposed():``),
+    else None — how the store attaches its waits to the caller's
+    phase without a parameter on every transaction."""
+    return getattr(_local, "rec", None)
+
+
+class _Exposed:
+    __slots__ = ("_rec", "_prev")
+
+    def __init__(self, rec: "CycleRec"):
+        self._rec = rec
+
+    def __enter__(self) -> "CycleRec":
+        self._prev = getattr(_local, "rec", None)
+        _local.rec = self._rec
+        return self._rec
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        _local.rec = self._prev
 
 
 class _Phase:
@@ -62,6 +135,10 @@ class _Phase:
         self.cpu_ms = 0.0
 
     def __enter__(self) -> "_Phase":
+        rec = self._rec
+        if rec._ann is not None:
+            rec._close(None)      # the unrecorded gap before the block
+        rec._open()
         self._pc0 = time.perf_counter()
         self._ct0 = time.thread_time()
         return self
@@ -74,6 +151,9 @@ class _Phase:
         rec = self._rec
         rec.phases.append((self._name, self._pc0, pc1, self.cpu_ms))
         rec._last, rec._clast = pc1, ct1
+        if rec._ann is not None:
+            rec._close(self._name)
+        rec._open()
 
 
 class CycleRec:
@@ -84,12 +164,13 @@ class CycleRec:
     be too.  Only :meth:`CycleProfiler.commit` is gated on enablement.
     """
 
-    __slots__ = ("kind", "pool", "t0", "t0_ms", "_c0", "_last", "_clast",
-                 "phases")
+    __slots__ = ("kind", "pool", "cycle", "t0", "t0_ms", "_c0", "_last",
+                 "_clast", "phases", "children", "attrs", "_ta", "_ann")
 
-    def __init__(self, kind: str, pool: str):
+    def __init__(self, kind: str, pool: str, cycle: Optional[int] = None):
         self.kind = kind
         self.pool = pool
+        self.cycle = cycle
         self.t0 = time.perf_counter()
         self.t0_ms = time.time() * 1e3
         self._c0 = time.thread_time()
@@ -97,6 +178,28 @@ class CycleRec:
         self._clast = self._c0
         # (name, pc0, pc1, cpu_ms) — perf_counter bounds + thread CPU
         self.phases: list = []
+        # (name, pc0, pc1) spans inside a phase; None until the first
+        self.children: Optional[list] = None
+        self.attrs: Optional[dict] = None
+        self._ta = _TA or _trace_annotation()
+        self._ann = None
+        self._open()
+
+    # -- the device trace's view of the segments ------------------------
+
+    def _open(self) -> None:
+        ta = self._ta
+        if ta is not None and ta.is_enabled():
+            self._ann = ta(self.kind)
+            self._ann.__enter__()
+
+    def _close(self, phase: Optional[str]) -> None:
+        ann, self._ann = self._ann, None
+        if phase is not None:
+            ann.set_metadata(phase=phase)
+        if self.cycle is not None:
+            ann.set_metadata(cycle=self.cycle)
+        ann.__exit__(None, None, None)
 
     @staticmethod
     def now() -> float:
@@ -113,6 +216,9 @@ class CycleRec:
         ct = time.thread_time()
         self.phases.append((name, self._last, pc, (ct - self._clast) * 1e3))
         self._last, self._clast = pc, ct
+        if self._ann is not None:
+            self._close(name)
+        self._open()
         return pc
 
     def phase(self, name: str) -> _Phase:
@@ -120,6 +226,25 @@ class CycleRec:
         optional segments like resync that must not swallow the
         surrounding gap)."""
         return _Phase(self, name)
+
+    def child(self, name: str, pc0: float, pc1: float) -> None:
+        """A span inside the open phase (``perf_counter`` bounds), e.g.
+        a lock or fsync wait; ``commit()`` names the phase it fell in."""
+        if self.children is None:
+            self.children = []
+        self.children.append((name, pc0, pc1))
+
+    def set_attr(self, key: str, value) -> None:
+        """An attr of the committed record (the caller's ``commit``
+        keywords win on a clash)."""
+        if self.attrs is None:
+            self.attrs = {}
+        self.attrs[key] = value
+
+    def exposed(self) -> _Exposed:
+        """``with rec.exposed():`` — :func:`current_record` returns
+        this record on this thread inside the block."""
+        return _Exposed(self)
 
     # -- derived reads -------------------------------------------------
 
@@ -216,13 +341,16 @@ class CycleProfiler:
 
     # -- the hot path --------------------------------------------------
 
-    def cycle(self, kind: str, pool: str) -> CycleRec:
+    def cycle(self, kind: str, pool: str,
+              cycle: Optional[int] = None) -> CycleRec:
         """Open a record for one cycle.  Always real — see CycleRec."""
-        return CycleRec(kind, pool)
+        return CycleRec(kind, pool, cycle)
 
     def commit(self, rec: CycleRec, **attrs) -> None:
         """Fold a finished record into the ledger.  Disabled: returns
         before allocating anything (the zero-cost always-on bargain)."""
+        if rec._ann is not None:
+            rec._close(None)      # the tail after the last boundary
         if not self.enabled:
             return
         end = time.perf_counter()
@@ -242,6 +370,15 @@ class CycleProfiler:
                  "wall_ms": round(wall_ms, 4),
                  "cpu_ms": round(cpu_ms, 4),
                  "phases": phases, "crit": crit_name}
+        if rec.children:
+            entry["children"] = [
+                {"name": name, "phase": next(
+                    (n for n, a, b, _c in rec.phases if a <= c0 < b), ""),
+                 "ms": round((c1 - c0) * 1e3, 4),
+                 "off_ms": round((c0 - rec.t0) * 1e3, 4)}
+                for name, c0, c1 in rec.children]
+        if rec.attrs:
+            attrs = {**rec.attrs, **attrs}
         if attrs:
             entry["attrs"] = attrs
         with self._lock:
@@ -319,6 +456,57 @@ class CycleProfiler:
             entries = list(self._ring)
         return self._rate(entries)
 
+    def consume_spans(self, spans: list) -> list:
+        """The children of each resident ``match.cycle`` job span in
+        ``spans`` — ``readback``, ``launch_loop``, ``launch_txn``,
+        ``backend_launch`` — built at read time from the consume record
+        of the span's (pool, cycle) while the ring still holds it. The
+        consume writes one span per launched job; its phases are the
+        same for every job of the cycle, so they are not copied per
+        job. ``launch_txn`` keeps the id the durable ``insts`` record
+        carries, ``backend_launch`` the id of the launch traceparent
+        (:func:`launch_span_id`)."""
+        want: dict = {}
+        for s in spans:
+            a = s.get("attrs")
+            if s.get("name") == "match.cycle" and a \
+                    and a.get("path") == "resident":
+                want.setdefault((a.get("pool"), a.get("cycle")), []).append(s)
+        if not want:
+            return []
+        with self._lock:
+            recs = {(e["pool"], e["attrs"].get("cycle")): e
+                    for e in self._ring
+                    if e["kind"] == "consume" and "attrs" in e}
+        out = []
+        for key, job_spans in want.items():
+            e = recs.get(key)
+            if e is None:
+                continue
+            t0 = e["t0_ms"]
+            ends = {p["name"]: t0 + p["off_ms"] + p["ms"]
+                    for p in e["phases"]}
+            rb1 = ends.get("readback", t0)
+            loop1 = ends.get("frame", rb1)
+            txn1 = ends.get("launch_txn", loop1)
+            end = t0 + e["wall_ms"]
+            txn_sid = e["attrs"].get("txn_span", "")
+            for s in job_spans:
+                sid = s["span"]
+                for name, span_id, a, b in (
+                        ("readback", _derived_id(sid, "readback"), t0, rb1),
+                        ("launch_loop", _derived_id(sid, "launch_loop"),
+                         rb1, loop1),
+                        ("launch_txn", txn_sid
+                         or _derived_id(sid, "launch_txn"), loop1, txn1),
+                        ("backend_launch",
+                         launch_span_id(s["attrs"].get("task", "")),
+                         txn1, end)):
+                    out.append({"name": name, "trace": s["trace"],
+                                "span": span_id, "parent": sid,
+                                "t0": round(a, 3), "t1": round(b, 3)})
+        return out
+
     def worst(self, k: int = 8) -> list:
         """The K slowest cycles currently in the ring, worst first."""
         with self._lock:
@@ -370,6 +558,19 @@ class CycleProfiler:
             self._blame.clear()
             self._cycles.clear()
             self._committed = 0
+
+
+def launch_span_id(task_id: str) -> str:
+    """The span id of a resident launch's ``backend_launch`` span: the
+    first 16 hex digits of its task id, so the launch traceparent the
+    backend receives and the span built at read time agree without
+    keeping the id per job."""
+    return task_id.replace("-", "")[:16]
+
+
+def _derived_id(span_id: str, name: str) -> str:
+    return hashlib.blake2b(f"{span_id}/{name}".encode(),
+                           digest_size=8).hexdigest()
 
 
 # Process-wide default, mirroring obs.trace.tracer.

@@ -18,7 +18,10 @@ rank loop's pool-name->pending-jobs-atom, :1281-1458):
      hosts (ops/match.py).
 
 Everything runs on device in one jit; the host only ships deltas of the
-job/offer tensors and reads back the assignment vector.
+job/offer tensors and reads back the assignment vector. The steps run
+under the named scopes ``rank``, ``filter``, ``head_scan`` and
+``epilogue`` (compaction and the decision window), so a device trace
+attributes each op to its step.
 """
 from __future__ import annotations
 
@@ -142,206 +145,213 @@ def rank_and_match(
     U = user_quota_mem.shape[0]
 
     # ---- 1. rank union of running + pending --------------------------
-    user = jnp.concatenate([run_user, pend_user])
-    prio = jnp.concatenate([run_prio, pend_prio])
-    start = jnp.concatenate([run_start, pend_start])
-    valid = jnp.concatenate([run_valid, pend_valid])
+    with jax.named_scope("rank"):
+        user = jnp.concatenate([run_user, pend_user])
+        prio = jnp.concatenate([run_prio, pend_prio])
+        start = jnp.concatenate([run_start, pend_start])
+        valid = jnp.concatenate([run_valid, pend_valid])
 
-    if dru_mode == "gpu":
-        gpus = jnp.concatenate([run_gpus, pend_gpus])
-        gshare = jnp.concatenate([run_gpu_share, pend_gpu_share])
-        ranked = dru_ops.gpu_dru_rank(user, gpus, prio, start, valid, gshare)
-    else:
-        mem = jnp.concatenate([run_mem, pend_mem])
-        cpus = jnp.concatenate([run_cpus, pend_cpus])
-        mshare = jnp.concatenate([run_mem_share, pend_mem_share])
-        cshare = jnp.concatenate([run_cpus_share, pend_cpus_share])
-        ranked = dru_ops.dru_rank(user, mem, cpus, prio, start, valid,
-                                  mshare, cshare)
-    pending_dru = ranked.dru[R:]
-    # fair-queue position among *pending* jobs only: order pending by
-    # their global rank.
-    pend_global_rank = ranked.rank[R:]
-    queue_perm = jnp.argsort(
-        jnp.where(pend_valid, pend_global_rank, jnp.iinfo(jnp.int32).max))
-    queue_rank = jnp.zeros(P, jnp.int32).at[queue_perm].set(
-        jnp.arange(P, dtype=jnp.int32))
+        if dru_mode == "gpu":
+            gpus = jnp.concatenate([run_gpus, pend_gpus])
+            gshare = jnp.concatenate([run_gpu_share, pend_gpu_share])
+            ranked = dru_ops.gpu_dru_rank(user, gpus, prio, start, valid,
+                                          gshare)
+        else:
+            mem = jnp.concatenate([run_mem, pend_mem])
+            cpus = jnp.concatenate([run_cpus, pend_cpus])
+            mshare = jnp.concatenate([run_mem_share, pend_mem_share])
+            cshare = jnp.concatenate([run_cpus_share, pend_cpus_share])
+            ranked = dru_ops.dru_rank(user, mem, cpus, prio, start, valid,
+                                      mshare, cshare)
+        pending_dru = ranked.dru[R:]
+        # fair-queue position among *pending* jobs only: order pending by
+        # their global rank.
+        pend_global_rank = ranked.rank[R:]
+        queue_perm = jnp.argsort(
+            jnp.where(pend_valid, pend_global_rank, jnp.iinfo(jnp.int32).max))
+        queue_rank = jnp.zeros(P, jnp.int32).at[queue_perm].set(
+            jnp.arange(P, dtype=jnp.int32))
 
     # ---- 2. considerable filter (quota + cap) ------------------------
-    # running usage per user
-    def usage(vals):
-        return jax.ops.segment_sum(jnp.where(run_valid, vals, 0.0),
-                                   jnp.where(run_valid, run_user, U),
-                                   num_segments=U + 1)[:U]
+    with jax.named_scope("filter"):
+        # running usage per user
+        def usage(vals):
+            return jax.ops.segment_sum(jnp.where(run_valid, vals, 0.0),
+                                       jnp.where(run_valid, run_user, U),
+                                       num_segments=U + 1)[:U]
 
-    u_mem = usage(run_mem)
-    u_cpus = usage(run_cpus)
-    u_cnt = jax.ops.segment_sum(run_valid.astype(jnp.float32),
-                                jnp.where(run_valid, run_user, U),
-                                num_segments=U + 1)[:U]
+        u_mem = usage(run_mem)
+        u_cpus = usage(run_cpus)
+        u_cnt = jax.ops.segment_sum(run_valid.astype(jnp.float32),
+                                    jnp.where(run_valid, run_user, U),
+                                    num_segments=U + 1)[:U]
 
-    # cumulative pending demand per user in queue order
-    q_user = pend_user[queue_perm]
-    q_valid = pend_valid[queue_perm]
-    sort_user = jnp.where(q_valid, q_user, U)
-    uperm = jnp.argsort(sort_user, stable=True)
-    su = sort_user[uperm]
-    cum = segment_cumsum(
-        jnp.stack([jnp.where(q_valid, pend_mem[queue_perm], 0.0)[uperm],
-                   jnp.where(q_valid, pend_cpus[queue_perm], 0.0)[uperm],
-                   q_valid[uperm].astype(jnp.float32)], -1), su)
-    uid = jnp.clip(su, 0, U - 1)
-    # signed per-dimension overage (positive = this dim would exceed the
-    # user's quota): the quota gate AND the provenance datum in one pass
-    over = jnp.stack(
-        [u_mem[uid] + cum[:, 0] - user_quota_mem[uid],
-         u_cpus[uid] + cum[:, 1] - user_quota_cpus[uid],
-         u_cnt[uid] + cum[:, 2] - user_quota_count[uid]], -1)
-    within = (over[:, 0] <= 0) & (over[:, 1] <= 0) & (over[:, 2] <= 0)
-    within_q = jnp.zeros(P, bool).at[uperm].set(within)      # queue order
-    over_q = jnp.zeros((P, 3)).at[uperm].set(over)           # queue order
-    considerable_q = q_valid & within_q
-    # cap at num_considerable (static, sets the compact batch shape) and
-    # at considerable_limit (dynamic, the scaleback feedback value —
-    # scheduler.clj:1002-1036 — which must not trigger a recompile)
-    cap = num_considerable if considerable_limit is None else \
-        jnp.minimum(jnp.int32(num_considerable),
-                    jnp.asarray(considerable_limit, jnp.int32))
-    taken = jnp.cumsum(considerable_q.astype(jnp.int32))
-    considerable_q &= taken <= cap
-    considerable = jnp.zeros(P, bool).at[queue_perm].set(considerable_q)
+        # cumulative pending demand per user in queue order
+        q_user = pend_user[queue_perm]
+        q_valid = pend_valid[queue_perm]
+        sort_user = jnp.where(q_valid, q_user, U)
+        uperm = jnp.argsort(sort_user, stable=True)
+        su = sort_user[uperm]
+        cum = segment_cumsum(
+            jnp.stack([jnp.where(q_valid, pend_mem[queue_perm], 0.0)[uperm],
+                       jnp.where(q_valid, pend_cpus[queue_perm], 0.0)[uperm],
+                       q_valid[uperm].astype(jnp.float32)], -1), su)
+        uid = jnp.clip(su, 0, U - 1)
+        # signed per-dimension overage (positive = this dim would exceed the
+        # user's quota): the quota gate AND the provenance datum in one pass
+        over = jnp.stack(
+            [u_mem[uid] + cum[:, 0] - user_quota_mem[uid],
+             u_cpus[uid] + cum[:, 1] - user_quota_cpus[uid],
+             u_cnt[uid] + cum[:, 2] - user_quota_count[uid]], -1)
+        within = (over[:, 0] <= 0) & (over[:, 1] <= 0) & (over[:, 2] <= 0)
+        within_q = jnp.zeros(P, bool).at[uperm].set(within)      # queue order
+        over_q = jnp.zeros((P, 3)).at[uperm].set(over)           # queue order
+        considerable_q = q_valid & within_q
+        # cap at num_considerable (static, sets the compact batch shape) and
+        # at considerable_limit (dynamic, the scaleback feedback value —
+        # scheduler.clj:1002-1036 — which must not trigger a recompile)
+        cap = num_considerable if considerable_limit is None else \
+            jnp.minimum(jnp.int32(num_considerable),
+                        jnp.asarray(considerable_limit, jnp.int32))
+        taken = jnp.cumsum(considerable_q.astype(jnp.int32))
+        considerable_q &= taken <= cap
+        considerable = jnp.zeros(P, bool).at[queue_perm].set(considerable_q)
 
-    # ---- 3. compact the considerable head, then match ----------------
-    # Gather the first num_considerable queue entries into a dense C-batch
-    # so the match kernel's (jobs x hosts) working set is C x H, not P x H
-    # (at 100k pending x 10k offers a dense P x H mask would be ~1 GB).
-    C = num_considerable
-    H = hosts.mem.shape[0]
-    cons_pos = jnp.cumsum(considerable_q.astype(jnp.int32)) - 1
-    slot = jnp.where(considerable_q, jnp.minimum(cons_pos, C), C)
-    # src[c] = queue position feeding compact slot c (P = empty slot)
-    src = jnp.full(C + 1, P, jnp.int32).at[slot].set(
-        jnp.arange(P, dtype=jnp.int32), mode="drop")[:C]
-    in_use = src < P
-    srcc = jnp.clip(src, 0, P - 1)
-    # compose queue_perm with the compact slots once, so each gather below
-    # is a direct (C,)-sized gather — never a (P, H) intermediate
-    pend_idx = queue_perm[srcc]
+        # ---- 3. compact the considerable head, then match ----------------
+    #         (the compaction is the filter's output; the match is the
+    #         head scan)
+        # Gather the first num_considerable queue entries into a dense C-batch
+        # so the match kernel's (jobs x hosts) working set is C x H, not P x H
+        # (at 100k pending x 10k offers a dense P x H mask would be ~1 GB).
+        C = num_considerable
+        H = hosts.mem.shape[0]
+        cons_pos = jnp.cumsum(considerable_q.astype(jnp.int32)) - 1
+        slot = jnp.where(considerable_q, jnp.minimum(cons_pos, C), C)
+        # src[c] = queue position feeding compact slot c (P = empty slot)
+        src = jnp.full(C + 1, P, jnp.int32).at[slot].set(
+            jnp.arange(P, dtype=jnp.int32), mode="drop")[:C]
+        in_use = src < P
+        srcc = jnp.clip(src, 0, P - 1)
+        # compose queue_perm with the compact slots once, so each gather below
+        # is a direct (C,)-sized gather — never a (P, H) intermediate
+        pend_idx = queue_perm[srcc]
 
-    def gq(arr):  # gather: original pending order -> compact batch
-        return arr[pend_idx]
+        def gq(arr):  # gather: original pending order -> compact batch
+            return arr[pend_idx]
 
-    jobs = match_ops.Jobs(
-        mem=gq(pend_mem), cpus=gq(pend_cpus), gpus=gq(pend_gpus),
-        valid=in_use,
-        group=gq(pend_group), unique_group=gq(pend_unique_group),
-    )
-    if forbidden is None:
-        forb = match_ops.varying_full(hosts.valid, False, (C, H), bool)
-    elif isinstance(forbidden, tuple):
-        rows, slot_of = forbidden
-        Kc = rows.shape[0]
-        slot = slot_of[pend_idx]
-        forb = jnp.where((slot >= 0)[:, None],
-                         rows[jnp.clip(slot, 0, Kc - 1)], False)
-        forb &= in_use[:, None]
-    else:
-        forb = forbidden[pend_idx] & in_use[:, None]
-    if pend_ports is not None and host_ports is not None:
-        forb = forb | (pend_ports[pend_idx][:, None] > host_ports[None, :])
-    if pend_est_s is not None and host_death_s is not None:
-        # est_end >= death forbids the host; est <= 0 = unconstrained
-        est = pend_est_s[pend_idx]
-        forb = forb | ((est > 0)[:, None]
-                       & ((now_s + est)[:, None] >= host_death_s[None, :]))
-    if bonus is None:
-        bonusc = None
-    elif isinstance(bonus, tuple):
-        brows, bslot = bonus
-        Kb = brows.shape[0]
-        bs = bslot[pend_idx]
-        bonusc = jnp.where((bs >= 0)[:, None],
-                           brows[jnp.clip(bs, 0, Kb - 1)], 0.0) \
-            * in_use[:, None]
-    else:
-        bonusc = bonus[pend_idx] * in_use[:, None]
-    if matcher is not None:
-        res = matcher(jobs, hosts, forb, bonusc)
-    elif sequential:
-        res = match_ops.match_scan(jobs, hosts, forb, num_groups=num_groups,
-                                   bonus=bonusc,
-                                   use_pallas=use_pallas and bonus is None)
-    else:
-        kw = {"rounds": 4, **dict(match_kw or ())}
-        res = match_ops.match_rounds(jobs, hosts, forb,
-                                     num_groups=num_groups, bonus=bonusc,
-                                     use_pallas=use_pallas, **kw)
-    # scatter back: compact -> original pending order in one scatter
-    # (empty compact slots get index P and are dropped)
-    scatter_idx = jnp.where(in_use, pend_idx, P)
-    job_host = jnp.full(P, match_ops.NO_HOST).at[scatter_idx].set(
-        res.job_host, mode="drop")
+        jobs = match_ops.Jobs(
+            mem=gq(pend_mem), cpus=gq(pend_cpus), gpus=gq(pend_gpus),
+            valid=in_use,
+            group=gq(pend_group), unique_group=gq(pend_unique_group),
+        )
+        if forbidden is None:
+            forb = match_ops.varying_full(hosts.valid, False, (C, H), bool)
+        elif isinstance(forbidden, tuple):
+            rows, slot_of = forbidden
+            Kc = rows.shape[0]
+            slot = slot_of[pend_idx]
+            forb = jnp.where((slot >= 0)[:, None],
+                             rows[jnp.clip(slot, 0, Kc - 1)], False)
+            forb &= in_use[:, None]
+        else:
+            forb = forbidden[pend_idx] & in_use[:, None]
+        if pend_ports is not None and host_ports is not None:
+            forb = forb | (pend_ports[pend_idx][:, None] > host_ports[None, :])
+        if pend_est_s is not None and host_death_s is not None:
+            # est_end >= death forbids the host; est <= 0 = unconstrained
+            est = pend_est_s[pend_idx]
+            forb = forb | ((est > 0)[:, None]
+                           & ((now_s + est)[:, None] >= host_death_s[None, :]))
+        if bonus is None:
+            bonusc = None
+        elif isinstance(bonus, tuple):
+            brows, bslot = bonus
+            Kb = brows.shape[0]
+            bs = bslot[pend_idx]
+            bonusc = jnp.where((bs >= 0)[:, None],
+                               brows[jnp.clip(bs, 0, Kb - 1)], 0.0) \
+                * in_use[:, None]
+        else:
+            bonusc = bonus[pend_idx] * in_use[:, None]
+    with jax.named_scope("head_scan"):
+        if matcher is not None:
+            res = matcher(jobs, hosts, forb, bonusc)
+        elif sequential:
+            res = match_ops.match_scan(
+                jobs, hosts, forb, num_groups=num_groups, bonus=bonusc,
+                use_pallas=use_pallas and bonus is None)
+        else:
+            kw = {"rounds": 4, **dict(match_kw or ())}
+            res = match_ops.match_rounds(jobs, hosts, forb,
+                                         num_groups=num_groups, bonus=bonusc,
+                                         use_pallas=use_pallas, **kw)
+    with jax.named_scope("epilogue"):
+        # scatter back: compact -> original pending order in one scatter
+        # (empty compact slots get index P and are dropped)
+        scatter_idx = jnp.where(in_use, pend_idx, P)
+        job_host = jnp.full(P, match_ops.NO_HOST).at[scatter_idx].set(
+            res.job_host, mode="drop")
 
-    # compact outputs: slot order IS queue order (slots were assigned by
-    # queue-position cumsum), so the launch loop walks cons_idx directly
-    cons_idx = jnp.where(in_use, pend_idx, -1).astype(jnp.int32)
-    matched_slot = in_use & (res.job_host >= 0)
-    head_matched = ~in_use[0] | (res.job_host[0] >= 0)
-    # compaction epilogue: pack the matched slots to the front with the
-    # same cumsum-position scatter used for the considerable batch above
-    # (slots are queue-ordered and the cumsum is monotone, so the prefix
-    # stays in queue order — the launch loop's walk order is unchanged)
-    mat_pos = jnp.cumsum(matched_slot.astype(jnp.int32)) - 1
-    mslot = jnp.where(matched_slot, jnp.minimum(mat_pos, C), C)
-    mat_idx = jnp.full(C + 1, -1, jnp.int32).at[mslot].set(
-        cons_idx, mode="drop")[:C]
-    mat_host = jnp.full(C + 1, -1, jnp.int32).at[mslot].set(
-        res.job_host.astype(jnp.int32), mode="drop")[:C]
+        # compact outputs: slot order IS queue order (slots were assigned by
+        # queue-position cumsum), so the launch loop walks cons_idx directly
+        cons_idx = jnp.where(in_use, pend_idx, -1).astype(jnp.int32)
+        matched_slot = in_use & (res.job_host >= 0)
+        head_matched = ~in_use[0] | (res.job_host[0] >= 0)
+        # compaction epilogue: pack the matched slots to the front with the
+        # same cumsum-position scatter used for the considerable batch above
+        # (slots are queue-ordered and the cumsum is monotone, so the prefix
+        # stays in queue order — the launch loop's walk order is unchanged)
+        mat_pos = jnp.cumsum(matched_slot.astype(jnp.int32)) - 1
+        mslot = jnp.where(matched_slot, jnp.minimum(mat_pos, C), C)
+        mat_idx = jnp.full(C + 1, -1, jnp.int32).at[mslot].set(
+            cons_idx, mode="drop")[:C]
+        mat_host = jnp.full(C + 1, -1, jnp.int32).at[mslot].set(
+            res.job_host.astype(jnp.int32), mode="drop")[:C]
 
-    # ---- 4. decision provenance --------------------------------------
-    # Reason code per fair-queue position over the window W = min(C, P)
-    # (static: queue-order vectors are (P,), the compact batch is (C,)).
-    # Every input below already exists in queue order — this is pure
-    # epilogue arithmetic, no new gathers over (P, H).
-    W = min(C, P)
-    wqp = queue_perm[:W]
-    wvalid = q_valid[:W]
-    whost = job_host[wqp]                 # host the position matched, -1
-    wcons = considerable_q[:W]            # survived quota AND cap
-    wwithin = within_q[:W]
-    wtaken = taken[:W]                    # pre-cap considerable ordinal
-    wover = over_q[:W]
-    # first-failing quota dimension, mem -> cpus -> count priority
-    quota_code = jnp.where(
-        wover[:, 0] > 0, why_codes.QUOTA_MEM,
-        jnp.where(wover[:, 1] > 0, why_codes.QUOTA_CPUS,
-                  why_codes.QUOTA_COUNT))
-    quota_amt = jnp.where(
-        wover[:, 0] > 0, wover[:, 0],
-        jnp.where(wover[:, 1] > 0, wover[:, 1], wover[:, 2]))
-    why_code = jnp.where(
-        ~wvalid, why_codes.INVALID,
-        jnp.where(wcons,
-                  jnp.where(whost >= 0, why_codes.MATCHED,
-                            why_codes.NO_HOST_FIT),
-                  jnp.where(~wwithin, quota_code,
-                            why_codes.RANK_CUTOFF))).astype(jnp.int32)
-    why_amt = jnp.where(
-        ~wvalid, 0.0,
-        jnp.where(wcons, jnp.where(whost >= 0, whost.astype(jnp.float32),
-                                   0.0),
-                  jnp.where(~wwithin, quota_amt,
-                            wtaken.astype(jnp.float32))))
-    why_idx = jnp.where(wvalid, wqp, -1).astype(jnp.int32)
+        # ---- 4. decision provenance --------------------------------------
+        # Reason code per fair-queue position over the window W = min(C, P)
+        # (static: queue-order vectors are (P,), the compact batch is (C,)).
+        # Every input below already exists in queue order — this is pure
+        # epilogue arithmetic, no new gathers over (P, H).
+        W = min(C, P)
+        wqp = queue_perm[:W]
+        wvalid = q_valid[:W]
+        whost = job_host[wqp]                 # host the position matched, -1
+        wcons = considerable_q[:W]            # survived quota AND cap
+        wwithin = within_q[:W]
+        wtaken = taken[:W]                    # pre-cap considerable ordinal
+        wover = over_q[:W]
+        # first-failing quota dimension, mem -> cpus -> count priority
+        quota_code = jnp.where(
+            wover[:, 0] > 0, why_codes.QUOTA_MEM,
+            jnp.where(wover[:, 1] > 0, why_codes.QUOTA_CPUS,
+                      why_codes.QUOTA_COUNT))
+        quota_amt = jnp.where(
+            wover[:, 0] > 0, wover[:, 0],
+            jnp.where(wover[:, 1] > 0, wover[:, 1], wover[:, 2]))
+        why_code = jnp.where(
+            ~wvalid, why_codes.INVALID,
+            jnp.where(wcons,
+                      jnp.where(whost >= 0, why_codes.MATCHED,
+                                why_codes.NO_HOST_FIT),
+                      jnp.where(~wwithin, quota_code,
+                                why_codes.RANK_CUTOFF))).astype(jnp.int32)
+        why_amt = jnp.where(
+            ~wvalid, 0.0,
+            jnp.where(wcons, jnp.where(whost >= 0, whost.astype(jnp.float32),
+                                       0.0),
+                      jnp.where(~wwithin, quota_amt,
+                                wtaken.astype(jnp.float32))))
+        why_idx = jnp.where(wvalid, wqp, -1).astype(jnp.int32)
 
-    return CycleResult(pending_dru=pending_dru, queue_rank=queue_rank,
-                       considerable=considerable, job_host=job_host,
-                       mem_left=res.mem_left, cpus_left=res.cpus_left,
-                       gpus_left=res.gpus_left, slots_left=res.slots_left,
-                       cons_idx=cons_idx, cons_host=res.job_host,
-                       head_matched=head_matched,
-                       n_matched=matched_slot.sum().astype(jnp.int32),
-                       n_considerable=in_use.sum().astype(jnp.int32),
-                       mat_idx=mat_idx, mat_host=mat_host,
-                       why_idx=why_idx, why_code=why_code,
-                       why_amt=why_amt)
+        return CycleResult(pending_dru=pending_dru, queue_rank=queue_rank,
+                           considerable=considerable, job_host=job_host,
+                           mem_left=res.mem_left, cpus_left=res.cpus_left,
+                           gpus_left=res.gpus_left, slots_left=res.slots_left,
+                           cons_idx=cons_idx, cons_host=res.job_host,
+                           head_matched=head_matched,
+                           n_matched=matched_slot.sum().astype(jnp.int32),
+                           n_considerable=in_use.sum().astype(jnp.int32),
+                           mat_idx=mat_idx, mat_host=mat_host,
+                           why_idx=why_idx, why_code=why_code,
+                           why_amt=why_amt)

@@ -959,7 +959,12 @@ class CookApi:
     # ------------------------------------------------------------------
     # submission (create-jobs! rest/api.clj:1805; validation :523+)
     def create_jobs(self, req: Request) -> Response:
-        return self._create_jobs_impl(req, bulk=False)
+        t0 = time.perf_counter()
+        try:
+            with obs.annotation("rest.submit"):
+                return self._create_jobs_impl(req, bulk=False)
+        finally:
+            _observe_submit(t0)
 
     def create_jobs_bulk(self, req: Request) -> Response:
         """High-throughput bulk submission (same payload shape as POST
@@ -969,7 +974,12 @@ class CookApi:
         very large arrays. Validation and atomicity are unchanged: the
         whole array is one transaction — any invalid job rejects the
         request with nothing created."""
-        return self._create_jobs_impl(req, bulk=True)
+        t0 = time.perf_counter()
+        try:
+            with obs.annotation("rest.submit"):
+                return self._create_jobs_impl(req, bulk=True)
+        finally:
+            _observe_submit(t0)
 
     def _create_jobs_impl(self, req: Request, bulk: bool) -> Response:
         t_submit0 = obs.now_ms()
@@ -1918,7 +1928,7 @@ class CookApi:
                     break
             if not trace_id:
                 raise ApiError(404, f"job {uuid} unknown")
-        spans = {s["span"]: s for s in obs.tracer.trace(trace_id)}
+        spans = {s["span"]: s for s in _trace_spans(trace_id)}
         if peers:
             # merge every peer's spans for this trace id; dedup by
             # span id (the migration span is recorded per-job with one
@@ -1944,7 +1954,7 @@ class CookApi:
         Deliberately dumb — never fans out — so a get_trace on any
         group terminates after one hop."""
         return Response(200, {"trace_id": trace_id,
-                              "spans": obs.tracer.trace(trace_id)})
+                              "spans": _trace_spans(trace_id)})
 
     def federation_trace_job(self, req: Request, uuid: str) -> Response:
         """Peer-facing uuid -> trace-id resolution from the LOCAL
@@ -1955,7 +1965,7 @@ class CookApi:
             raise ApiError(404, f"job {uuid} unknown or untraced")
         return Response(200, {"uuid": uuid, "trace_id": ctx[0],
                               "traceparent": job.traceparent,
-                              "spans": obs.tracer.trace(ctx[0])})
+                              "spans": _trace_spans(ctx[0])})
 
     def get_debug_flight(self, req: Request) -> Response:
         """The coordinator's cycle flight recorder: the most recent
@@ -2123,6 +2133,21 @@ class CookApi:
 
 # ----------------------------------------------------------------------
 # response shaping (the JobResponse/InstanceResponse schemas)
+def _trace_spans(trace_id: str) -> list:
+    """This process's spans of one trace: the recorded ones plus the
+    resident consume's per-job phase spans, built from the cycle
+    profiler's consume records at read time."""
+    spans = obs.tracer.trace(trace_id)
+    return spans + obs.profiler.consume_spans(spans)
+
+
+def _observe_submit(t0: float) -> None:
+    """``rest_submit_ms``: a submit handler's entry to its response
+    built, whatever the outcome."""
+    obs.metrics_registry.histogram("rest_submit_ms").observe(
+        (time.perf_counter() - t0) * 1e3)
+
+
 def _job_status(job: Job) -> str:
     return job.state.value
 

@@ -34,6 +34,7 @@ import threading
 import time
 from typing import Iterable, Optional
 
+from cook_tpu.obs.profiler import annotation
 from cook_tpu.state.store import TransactionError
 from cook_tpu.utils.metrics import registry
 
@@ -163,13 +164,18 @@ class IngestBatcher:
             wait = registry.histogram("ingest_wait_ms")
             for p in batch:
                 wait.observe(max(0.0, (now - p.ts) * 1e3))
+            t0 = time.perf_counter()
             try:
-                self._commit(batch)
+                with annotation("ingest.commit"):
+                    self._commit(batch)
             except BaseException:   # never let a worker die silently
                 log.exception("ingest worker: unexpected commit failure")
                 for p in batch:
                     if not p.done.is_set():
                         p.reject(OSError("ingest commit failed"))
+            # the batch's store transactions, their barrier included
+            registry.histogram("ingest_commit_ms").observe(
+                (time.perf_counter() - t0) * 1e3)
 
     def _commit(self, batch: list) -> None:
         """Commit a drained batch: coalesce what is safely coalescable

@@ -768,8 +768,10 @@ class Coordinator:
 
         pipeline_depth=0 is the classic serial cycle; async pools get
         the same overlap from the depth-2 consume queue instead."""
-        rec = obs.profiler.cycle("match", pool)
+        # the cycle this call dispatches (dispatch() then advances it)
+        rec = obs.profiler.cycle("match", pool, rp.cycle_no)
         stats = MatchStats()
+        caps0, resynced = (rp.Pcap, rp.Rcap, rp.Hcap), None
         self._purge_reservations()
         # periodic drift backstop: LIGHT membership reconcile (no
         # in-flight drain, no re-upload). A full rebuild — host-set /
@@ -797,6 +799,7 @@ class Coordinator:
                                  "back to sync rebuild", e)
                     if not swapped:
                         rp.resync()
+                resynced = "background"
                 swap_ms = ph.ms
                 self.metrics[f"match.{pool}.resync_ms"] = swap_ms
                 self.metrics[f"match.{pool}.rebuild_build_ms"] = \
@@ -850,6 +853,7 @@ class Coordinator:
                         reason = "full"
                         self.drain_resident(pool)
                         rp.resync()
+            resynced = reason
             self.metrics[f"match.{pool}.resync_ms"] = ph.ms
             metrics_registry.histogram(
                 "resync_ms", pool=pool, reason=str(reason)).observe(ph.ms)
@@ -867,6 +871,7 @@ class Coordinator:
                 with rec.phase("resync") as ph:
                     self.drain_resident(pool)
                     rp.resync()
+                resynced = "overflow"
                 self.metrics[f"match.{pool}.resync_ms"] = ph.ms
                 metrics_registry.histogram(
                     "resync_ms", pool=pool, reason="overflow").observe(
@@ -976,6 +981,17 @@ class Coordinator:
                 phases=rec.walls(),
                 attrs={"pool": pool, "cycle": rp.cycle_no,
                        "matched": stats.matched})
+        caps1 = (rp.Pcap, rp.Rcap, rp.Hcap)
+        if caps1 != caps0:
+            # a resync re-bucketed the device tables (a new program
+            # shape): count each growth where it happens, and name the
+            # caps and the resync that did it on this cycle's record
+            for name, a, b in zip(("Pcap", "Rcap", "Hcap"), caps0, caps1):
+                rec.set_attr(name, b)
+                if b > a:
+                    metrics_registry.counter(
+                        "resident_cap_grow_total", cap=name).inc()
+            rec.set_attr("resync", str(resynced))
         obs.profiler.commit(rec, cycle=rp.cycle_no,
                             matched=stats.matched)
         return stats
@@ -984,7 +1000,7 @@ class Coordinator:
         """Block on one cycle's compact readback, run the bulk launch
         transaction, hand specs to the backends. Returns cycle stats."""
         import jax
-        rec = obs.profiler.cycle("consume", pool)
+        rec = obs.profiler.cycle("consume", pool, out.cycle_no)
         # scalars first: 3 values tell us exactly how much else to pull
         head_matched, n_matched, n_considerable = jax.device_get(
             (out.head_matched, out.n_matched, out.n_considerable))
@@ -1030,7 +1046,7 @@ class Coordinator:
             # decision-overhead bench measures
             why_rows = jax.device_get(
                 (out.why_idx, out.why_code, out.why_amt))
-        pc_rb1 = rec.stamp("readback")
+        rec.stamp("readback")
         self.metrics[f"match.{pool}.readback_ms"] = rec.ms("readback")
         items = []        # (uuid, hostname, cluster_name, task_id)
         item_jobs = []    # (job, ports, credit_snapshot, spec, trace)
@@ -1176,9 +1192,11 @@ class Coordinator:
             if job.traceparent and obs.tracer.enabled:
                 ctx = obs.parse_traceparent(job.traceparent)
                 if ctx is not None:
-                    launch_sid = obs.new_span_id()
-                    tp_launch = obs.make_traceparent(ctx[0], launch_sid)
-                    tr = (ctx[0], ctx[1], launch_sid)
+                    # the backend_launch span /trace builds at read time
+                    # (profiler.consume_spans) carries the same id
+                    tp_launch = obs.make_traceparent(
+                        ctx[0], obs.launch_span_id(task_id))
+                    tr = (ctx[0], ctx[1])
             spec = LaunchSpec(
                 task_id=task_id, job_uuid=uuid,
                 hostname=hostname, command=job.command,
@@ -1202,7 +1220,7 @@ class Coordinator:
             with rp.mirror_lock:
                 for uuid, until in deferrals:
                     rp.defer_job_locked(uuid, until)
-        pc_loop = rec.stamp("frame")
+        rec.stamp("frame")
         self.metrics[f"match.{pool}.launch_loop_ms"] = \
             rec.ms("fold") + rec.ms("frame")
         self.metrics[f"match.{pool}.consume_frame_ms"] = rec.ms("frame")
@@ -1213,13 +1231,22 @@ class Coordinator:
         # rebuilds from the last committed event (zero-cost disarmed)
         procfault.kill_point("consume.window")
         # one span id for the whole bulk launch transaction: it rides
-        # on the durable "insts" log record AND appears (same id) as
-        # the launch_txn child in every launched traced job's tree
+        # on the durable "insts" log record AND on the consume record,
+        # from which /trace builds the launch_txn child of every
+        # launched traced job (profiler.consume_spans)
         txn_sid = obs.new_span_id() if obs.tracer.enabled and any(
             j.traceparent for j, _p, _c, _s, _t in item_jobs) else ""
-        insts = self.store.create_instances_bulk(
-            items, origin=("resident", pool, out.cycle_no),
-            span_id=txn_sid) if items else []
+        if txn_sid:
+            rec.set_attr("txn_span", txn_sid)
+        if items:
+            # the store records its shard-lock and fsync waits as
+            # children of this phase (profiler.current_record)
+            with rec.exposed():
+                insts = self.store.create_instances_bulk(
+                    items, origin=("resident", pool, out.cycle_no),
+                    span_id=txn_sid)
+        else:
+            insts = []
         rec.stamp("launch_txn")
         self.metrics[f"match.{pool}.launch_txn_ms"] = \
             rec.ms("launch_txn")
@@ -1231,7 +1258,7 @@ class Coordinator:
                     self.metrics[f"match.{pool}.launch_txn_ms"])
         by_cluster: dict[str, list[LaunchSpec]] = {}
         launched = 0
-        traced = []   # (trace_id, root_sid, launch_sid, task_id)
+        traced = []   # (trace_id, root_sid, task_id)
         for (uuid, hostname, cname, _tid), \
                 (job, ports, credit, spec, tr), inst in zip(
                 items, item_jobs, insts):
@@ -1250,7 +1277,7 @@ class Coordinator:
                 continue
             inst.ports = ports
             if tr is not None:
-                traced.append((tr[0], tr[1], tr[2], inst.task_id))
+                traced.append((tr[0], tr[1], inst.task_id))
             by_cluster.setdefault(cname, []).append(spec)
             launched += 1
             if inst.start_time_ms and job.submit_time_ms:
@@ -1344,39 +1371,23 @@ class Coordinator:
                     self.metrics[f"match.{pool}.backend_launch_ms"],
             })
         if obs.tracer.enabled:
-            # flight-recorder entry (cycle-level) + per-traced-job span
-            # reconstruction from the profiler record's stamps — no
-            # extra clocks, no device work, nothing on the hot path
-            # when tracing is disabled
+            # flight-recorder entry (cycle-level) + ONE span per traced
+            # launched job; its phase children are built at read time
+            # from this cycle's consume record (profiler.consume_spans)
+            # — no extra clocks, no device work, nothing on the hot
+            # path when tracing is disabled
             end = obs.now_ms()
-            txn_ms = self.metrics[f"match.{pool}.launch_txn_ms"]
-            wall_rb0 = rec.t0_ms
-            wall_rb1 = rec.wall_ms(pc_rb1)
-            wall_loop = rec.wall_ms(pc_loop)
-            wall_txn = wall_loop + txn_ms
             obs.tracer.record_cycle(
-                "cycle.consume", wall_rb0, end,
+                "cycle.consume", rec.t0_ms, end,
                 phases=rec.walls(),
                 attrs={"pool": pool, "cycle": out.cycle_no,
                        "matched": launched})
-            for tid, root_sid, launch_sid, task_id in traced:
-                cyc_sid = obs.tracer.record(
+            for tid, root_sid, task_id in traced:
+                obs.tracer.record(
                     "match.cycle", trace_id=tid, parent_id=root_sid,
-                    start_ms=wall_rb0, end_ms=end,
+                    start_ms=rec.t0_ms, end_ms=end,
                     attrs={"pool": pool, "cycle": out.cycle_no,
                            "task": task_id, "path": "resident"})
-                obs.tracer.record("readback", trace_id=tid,
-                                  parent_id=cyc_sid, start_ms=wall_rb0,
-                                  end_ms=wall_rb1)
-                obs.tracer.record("launch_loop", trace_id=tid,
-                                  parent_id=cyc_sid, start_ms=wall_rb1,
-                                  end_ms=wall_loop)
-                obs.tracer.record("launch_txn", trace_id=tid,
-                                  span_id=txn_sid, parent_id=cyc_sid,
-                                  start_ms=wall_loop, end_ms=wall_txn)
-                obs.tracer.record("backend_launch", trace_id=tid,
-                                  span_id=launch_sid, parent_id=cyc_sid,
-                                  start_ms=wall_txn, end_ms=end)
         obs.profiler.commit(rec, cycle=out.cycle_no, matched=launched)
         rp.consumed_through = out.cycle_no
         if rp._inflight and rp._inflight[0] is out:
@@ -2525,9 +2536,16 @@ class Coordinator:
         reference's deposed leader suicides and Datomic's single
         transactor refuses it anyway)."""
         self._leadership_check = leadership_check
-        def loop(interval, fn, per_pool=True, gate=None):
+        def loop(interval, fn, per_pool=True, gate=None, wait_name=None):
+            wait = self._stop.wait
+            if wait_name is not None:
+                def wait(s, _wait=self._stop.wait):
+                    # the sleep between ticks, named in a device trace
+                    with obs.annotation(wait_name):
+                        return _wait(s)
+
             def body():
-                while not self._stop.wait(interval):
+                while not wait(interval):
                     try:
                         if leadership_check is not None \
                                 and not leadership_check():
@@ -2546,7 +2564,7 @@ class Coordinator:
             self._threads.append(t)
 
         loop(self.config.match_interval_s, self.match_cycle,
-             gate=self._match_gate)
+             gate=self._match_gate, wait_name="match.tick_wait")
         loop(self.config.rebalancer_interval_s, self.rebalance_cycle)
         loop(60.0, self.watchdog_cycle, per_pool=False)
         opt = getattr(self, "optimizer_cycle", None)

@@ -17,7 +17,7 @@ from typing import Optional
 
 from cook_tpu.state.limits import ShareStore, UNLIMITED
 from cook_tpu.state.store import JobStore
-from cook_tpu.utils.metrics import MetricRegistry
+from cook_tpu.obs.metrics import Registry
 
 RESOURCES = ("mem", "cpus")
 
@@ -52,7 +52,7 @@ class StatsMonitor:
     """set-stats-counters! (monitor.clj:125-176) with stale clearing."""
 
     def __init__(self, store: JobStore, shares: ShareStore,
-                 registry: MetricRegistry):
+                 registry: Registry):
         self.store = store
         self.shares = shares
         self.registry = registry

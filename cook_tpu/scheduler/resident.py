@@ -214,12 +214,13 @@ def _device_cycle(state, deltas, qm, qc, qn, considerable_limit, now_s,
                   matcher=None):
     (p_idx, pf, pi, r_idx, rf, ri, c_idx, cf, ci, f_idx, frows,
      b_idx, brows) = deltas
-    p = _apply_pend(state["pend"], p_idx, pf, pi)
-    r = _apply_run(state["run"], r_idx, rf, ri)
-    h = _apply_credit(state["host"], c_idx, cf, ci)
-    state = {**state, "pend": p, "run": r, "host": h,
-             "forb": state["forb"].at[f_idx].set(frows, mode="drop"),
-             "bonus": state["bonus"].at[b_idx].set(brows, mode="drop")}
+    with jax.named_scope("apply_deltas"):
+        p = _apply_pend(state["pend"], p_idx, pf, pi)
+        r = _apply_run(state["run"], r_idx, rf, ri)
+        h = _apply_credit(state["host"], c_idx, cf, ci)
+        state = {**state, "pend": p, "run": r, "host": h,
+                 "forb": state["forb"].at[f_idx].set(frows, mode="drop"),
+                 "bonus": state["bonus"].at[b_idx].set(brows, mode="drop")}
     hosts = match_ops.Hosts(
         mem=h["mem"], cpus=h["cpus"], gpus=h["gpus"],
         cap_mem=h["cap_mem"], cap_cpus=h["cap_cpus"],
@@ -246,24 +247,26 @@ def _device_cycle(state, deltas, qm, qc, qn, considerable_limit, now_s,
         now_s=now_s if with_est else None,
         matcher=matcher)
     Pcap = p["valid"].shape[0]
-    # matched rows leave the pending set ON DEVICE, immediately: the
-    # readback lag can then never double-launch (see module docstring)
-    matched = (res.cons_idx >= 0) & (res.cons_host >= 0)
-    inval = jnp.where(matched, res.cons_idx, Pcap)
-    pend = dict(p)
-    pend["valid"] = p["valid"].at[inval].set(False, mode="drop")
-    # the match result IS the new host availability
-    host = dict(h)
-    host["mem"], host["cpus"], host["gpus"] = \
-        res.mem_left, res.cpus_left, res.gpus_left
-    host["task_slots"] = res.slots_left
-    # approximate in-kernel port depletion for matched jobs (exact
-    # port-number assignment stays host-side at launch)
-    want = jnp.where(matched, p["ports"][jnp.clip(res.cons_idx, 0, Pcap - 1)],
-                     0)
-    H = h["ports"].shape[0]
-    host["ports"] = h["ports"] - jax.ops.segment_sum(
-        want, jnp.where(matched, res.cons_host, H), num_segments=H + 1)[:H]
+    with jax.named_scope("epilogue"):
+        # matched rows leave the pending set ON DEVICE, immediately: the
+        # readback lag can then never double-launch (module docstring)
+        matched = (res.cons_idx >= 0) & (res.cons_host >= 0)
+        inval = jnp.where(matched, res.cons_idx, Pcap)
+        pend = dict(p)
+        pend["valid"] = p["valid"].at[inval].set(False, mode="drop")
+        # the match result IS the new host availability
+        host = dict(h)
+        host["mem"], host["cpus"], host["gpus"] = \
+            res.mem_left, res.cpus_left, res.gpus_left
+        host["task_slots"] = res.slots_left
+        # approximate in-kernel port depletion for matched jobs (exact
+        # port-number assignment stays host-side at launch)
+        want = jnp.where(
+            matched, p["ports"][jnp.clip(res.cons_idx, 0, Pcap - 1)], 0)
+        H = h["ports"].shape[0]
+        host["ports"] = h["ports"] - jax.ops.segment_sum(
+            want, jnp.where(matched, res.cons_host, H),
+            num_segments=H + 1)[:H]
     new_state = {**state, "pend": pend, "host": host}
     out = (res.cons_idx, res.cons_host, res.head_matched, res.n_matched,
            res.n_considerable, res.mat_idx, res.mat_host,

@@ -47,6 +47,7 @@ import zlib
 from cook_tpu import chaos
 from cook_tpu.chaos import procfault
 from cook_tpu.native import consumefold
+from cook_tpu.obs.profiler import annotation, current_record
 from cook_tpu.utils.lockwitness import witness_condition, witness_lock
 from dataclasses import asdict, dataclass
 from typing import Any, Callable, Iterable, Optional
@@ -278,7 +279,8 @@ class _GroupCommitBarrier:
         self.rounds = 0            # underlying writer.sync() calls
         self.waits = 0             # transactions that joined a round
 
-    def sync(self, writer) -> None:
+    def sync(self, writer) -> bool:
+        """True when this caller led the round (ran writer.sync())."""
         cv = self._cv
         with cv:
             self.waits += 1
@@ -318,10 +320,11 @@ class _GroupCommitBarrier:
                         pass
                 if err is not None:
                     raise err
-                return
+                return True
             err = self._errs.get(target)
             if err is not None:
                 raise err
+            return False
 
 
 @dataclass
@@ -567,20 +570,28 @@ class JobStore:
                                 (t2 - t1) * 1e3)
 
     @contextlib.contextmanager
-    def _pools_section(self, pools, txn: bool = False):
+    def _pools_section(self, pools, txn: bool = False, rec=None):
         """Multi-shard section for cross-pool batches (a mixed-pool
         create_jobs / commit_jobs): acquires the deduped shard locks
         in ascending index order — the fixed order that keeps two
         concurrent batches deadlock-free. An empty pool set acquires
         nothing (an all-invalid batch still runs its writability
-        check)."""
+        check). ``rec``: the caller's cycle record, which gets the
+        lock wait as a ``lock_wait`` child span."""
         idxs = sorted({zlib.crc32(p.encode()) % self.store_shards
                        for p in pools})
         record = txn and not getattr(self, "_replaying", False)
         t0 = time.perf_counter()
-        for i in idxs:
-            self._shard_locks[i].acquire()
+        if rec is None:
+            for i in idxs:
+                self._shard_locks[i].acquire()
+        else:
+            with annotation("store.lock_wait"):
+                for i in idxs:
+                    self._shard_locks[i].acquire()
         t1 = time.perf_counter()
+        if rec is not None:
+            rec.child("lock_wait", t0, t1)
         try:
             if record:
                 for i in idxs:
@@ -858,7 +869,7 @@ class JobStore:
             except Exception:
                 pass
 
-    def _barrier(self) -> None:
+    def _barrier(self) -> int:
         """Durability barrier, called once at the end of every public
         transaction: block until everything appended so far is
         fdatasync'd (the transactor ack the reference relies on before
@@ -886,12 +897,15 @@ class JobStore:
         turns out closed knows its appends are already durable — a
         sync failure is only re-raised when the handle is still the
         live writer (checked under the lock, so a mid-swap window
-        resolves before the verdict)."""
+        resolves before the verdict).
+
+        Returns the fsync rounds this caller led: 1 when it ran the
+        writer's sync itself, 0 when another round covered it."""
         if getattr(self, "_replaying", False):
-            return
+            return 0
         w = self._log
         if w is None or not hasattr(w, "sync"):
-            return
+            return 0
         try:
             if chaos.controller.enabled:
                 a = chaos.controller.act("store.fsync")
@@ -905,14 +919,15 @@ class JobStore:
                     # transaction it would have hit without coalescing
                     raise OSError("chaos[store.fsync]: injected failure")
             if self.group_commit:
-                self._writer_barrier(w).sync(w)
-            else:
-                w.sync()
+                return int(self._writer_barrier(w).sync(w))
+            w.sync()
+            return 1
         except Exception:
             with self._lock:
                 still_live = w is self._log
             if still_live:
                 raise
+            return 0
 
     def _writer_barrier(self, w) -> _GroupCommitBarrier:
         """The writer's group-commit barrier, attached lazily. One
@@ -1253,7 +1268,10 @@ class JobStore:
         # the section is re-checked by allowed_to_start inside it
         pools = {j.pool for it in items
                  if (j := self.jobs.get(it[0])) is not None}
-        with self._pools_section(pools, txn=True):
+        # a consume that exposes its cycle record gets this batch's
+        # lock and fsync waits as children of its launch_txn phase
+        rec = current_record()
+        with self._pools_section(pools, txn=True, rec=rec):
             self._check_writable(pools=pools)
             out = []
             created = []
@@ -1319,7 +1337,14 @@ class JobStore:
             # crash-soak schedule F pins zero lost / zero duplicated
             # instances across restart reconciliation for this window
             procfault.kill_point("store.launch_group_commit")
-        self._barrier()
+        if rec is None:
+            self._barrier()
+            return out
+        t0 = time.perf_counter()
+        with annotation("store.fsync_wait"):
+            led = self._barrier()
+        rec.child("fsync_wait", t0, time.perf_counter())
+        rec.set_attr("fsync_rounds", led)
         return out
 
     def update_instance(self, task_id: str, status: InstanceStatus,

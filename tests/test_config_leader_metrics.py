@@ -15,8 +15,8 @@ from cook_tpu.scheduler.monitor import StatsMonitor, starved_stats
 from cook_tpu.state.limits import ShareStore
 from cook_tpu.state.model import Job, new_uuid
 from cook_tpu.state.store import JobStore
-from cook_tpu.utils.metrics import (ConsoleReporter, MetricRegistry,
-                                    JsonlReporter)
+from cook_tpu.obs.metrics import Registry
+from cook_tpu.utils.metrics import ConsoleReporter, JsonlReporter
 
 
 # -- config ------------------------------------------------------------
@@ -264,12 +264,14 @@ def test_cross_process_exclusion(tmp_path):
 
 # -- metrics -----------------------------------------------------------
 def test_metric_kinds():
-    reg = MetricRegistry()
+    reg = Registry()
     reg.counter("c").inc(5)
     reg.counter("c").inc(-2)
     reg.meter("m").mark(10)
+    # unit-wide buckets: the bucket-interpolated p95 is good to one unit
+    unit = tuple(float(b) for b in range(1, 101))
     for v in range(100):
-        reg.histogram("h").update(v)
+        reg.histogram("h", buckets=unit).update(v)
     with reg.timer("t").time():
         pass
     snap = reg.snapshot()
@@ -280,7 +282,7 @@ def test_metric_kinds():
 
 
 def test_jsonl_reporter(tmp_path):
-    reg = MetricRegistry()
+    reg = Registry()
     reg.counter("x").inc()
     path = str(tmp_path / "metrics.jsonl")
     rep = JsonlReporter(reg, path, interval_s=0.05)
@@ -301,7 +303,7 @@ def test_starved_hungry_satisfied():
     store = JobStore()
     shares = ShareStore()
     shares.set("default", "default", mem=500, cpus=5)
-    reg = MetricRegistry()
+    reg = Registry()
     mon = StatsMonitor(store, shares, reg)
 
     # alice: running 100 MB (below 500 share), waiting more → starved
@@ -342,7 +344,7 @@ def test_graphite_reporter_plaintext_protocol():
     import socket
     import threading
 
-    from cook_tpu.utils.metrics import GraphiteReporter, MetricRegistry
+    from cook_tpu.utils.metrics import GraphiteReporter
 
     received = []
     srv = socket.socket()
@@ -363,7 +365,7 @@ def test_graphite_reporter_plaintext_protocol():
 
     t = threading.Thread(target=accept)
     t.start()
-    reg = MetricRegistry()
+    reg = Registry()
     reg.counter("match.cycles").inc(7)
     reg.timer("cycle ms").update(12.5)
     rep = GraphiteReporter(reg, "127.0.0.1", port, prefix="cook")

@@ -17,8 +17,8 @@ import time
 import pytest
 
 from cook_tpu import obs
-from cook_tpu.utils.metrics import (GraphiteReporter, Meter,
-                                    MetricRegistry, render_prometheus)
+from cook_tpu.obs.metrics import Meter, Registry
+from cook_tpu.utils.metrics import GraphiteReporter
 
 
 @pytest.fixture(autouse=True)
@@ -215,38 +215,45 @@ def test_to_chrome_trace_shapes():
 
 
 # ----------------------------------------------------------------------
-# satellite: render_prometheus / GraphiteReporter edge cases
+# satellite: Prometheus exposition (Registry.render) / GraphiteReporter
+# edge cases
 
 def test_render_prometheus_sanitises_names_and_digits():
-    text = render_prometheus({
-        "match.default.cycle-ms": {"type": "counter", "value": 3.0},
-        "9lives": {"type": "counter", "value": 1.0},
-    })
-    assert "cook_match_default_cycle_ms 3.0" in text
-    assert "cook__9lives 1.0" in text         # digit-led name prefixed
+    reg = Registry()
+    reg.counter("match.default.cycle-ms").inc(3)
+    reg.counter("9lives").inc()
+    text = reg.render()
+    assert "cook_match_default_cycle_ms 3" in text
+    assert "cook__9lives 1" in text           # digit-led name prefixed
     assert text.endswith("\n")
 
 
 def test_render_prometheus_quantiles_and_meter():
-    text = render_prometheus({
-        "cycle": {"type": "timer", "count": 7, "mean": 2.5,
-                  "p50": 1.0, "p95": 3.0, "p99": 4.0},
-        "done": {"type": "meter", "count": 10.0, "rate": 0.5},
-    })
-    assert 'cook_cycle{quantile="0.5"} 1' in text
-    assert 'cook_cycle{quantile="0.95"} 3' in text
-    assert 'cook_cycle{quantile="0.99"} 4' in text
+    reg = Registry()
+    for v in range(1, 8):
+        reg.timer("cycle").update(float(v))
+    reg.meter("done").mark(10)
+    text = reg.render()
+    # exact reservoir quantiles, linear interpolation over 1..7
+    assert 'cook_cycle{quantile="0.5"} 4' in text
+    assert 'cook_cycle{quantile="0.95"} 6.7' in text
+    assert 'cook_cycle{quantile="0.99"} 6.94' in text
     assert "cook_cycle_count 7" in text
-    assert "cook_cycle_mean 2.5" in text
-    assert "cook_done_total 10.0" in text
-    assert "cook_done_rate 0.5" in text
+    assert "cook_cycle_mean 4" in text
+    assert "cook_done_total 10" in text
+    assert "cook_done_rate 0.166667" in text  # 10 events / 60 s window
 
 
 def test_render_prometheus_empty_snapshot_and_missing_quantiles():
-    assert render_prometheus({}) == "\n"
-    # a fresh histogram snapshots as {"count": 0} — no quantile lines
-    text = render_prometheus({"h": {"type": "histogram", "count": 0}})
-    assert "quantile" not in text and "cook_h_count 0" in text
+    assert Registry().render() == "\n"
+    # a timer with no samples renders no quantile lines, a fresh
+    # histogram only its zero buckets
+    reg = Registry()
+    reg.timer("t")
+    reg.histogram("h")
+    text = reg.render()
+    assert "quantile" not in text
+    assert "cook_t_count 0" in text and "cook_h_count 0" in text
 
 
 def test_graphite_flatten_skips_type_and_collapses_value():
@@ -281,11 +288,12 @@ def test_meter_window_trims_old_events():
 
 
 def test_metric_registry_snapshot_roundtrips_through_prometheus():
-    reg = MetricRegistry()
+    reg = Registry()
     reg.counter("cycles").inc(2)
     reg.timer("cycle_ms").update(3.0)
-    text = render_prometheus(reg.snapshot())
-    assert "cook_cycles 2.0" in text
+    assert reg.snapshot()["cycles"] == {"type": "counter", "value": 2.0}
+    text = reg.render()
+    assert "cook_cycles 2" in text
     assert 'cook_cycle_ms{quantile="0.5"} 3' in text
 
 
