@@ -33,12 +33,17 @@ from cook_tpu.backends.base import LaunchSpec
 
 
 class Ledger:
-    """What the backend saw, shared by every cluster of one run."""
+    """What the backend saw, shared by every cluster of one run. With
+    pools, `pool_of` (uuid -> pool, the benchmark's own record of where
+    it submitted each job) splits the launches by pool."""
 
-    def __init__(self, jobs: dict, seeded_running: set, caps: dict):
+    def __init__(self, jobs: dict, seeded_running: set, caps: dict,
+                 pool_of: dict = None):
         self.jobs = jobs                  # uuid -> (user, mem, cpus, runtime)
         self.seeded_running = seeded_running
         self.caps = caps                  # hostname -> (mem, cpus)
+        self.pool_of = {} if pool_of is None else pool_of
+        self.pool_receipts: dict = {}     # pool -> [perf_counter] of launches
         self.used: dict = {}              # hostname -> [mem, cpus]
         self.launched: dict = {}          # uuid -> perf_counter at receipt
         self.task_job: dict = {}          # task id -> job uuid
@@ -63,6 +68,8 @@ class Ledger:
                     self.double += 1
                 else:
                     self.launched[u] = now
+                    self.pool_receipts.setdefault(
+                        self.pool_of.get(u), []).append(now)
                 self.receipts.append(now)
                 cap = self.caps.get(s.hostname)
                 used = self.used.setdefault(s.hostname, [0.0, 0.0])
@@ -72,6 +79,10 @@ class Ledger:
                     continue
                 used[0] += s.mem
                 used[1] += s.cpus
+
+    def launched_in(self, pool) -> int:
+        """Jobs of `pool` launched so far (first launches only)."""
+        return len(self.pool_receipts.get(pool, ()))
 
     def release(self, spec) -> None:
         with self.lock:

@@ -10,6 +10,10 @@ Two shapes, chosen by the traffic file's `mode`:
   what the backend launched, in POSTs of a fixed size, each from one
   uniformly drawn user.
 
+With several pools each pool has a generator of its own (its own
+schedule or target, its own workers) whose POSTs name the pool, and
+`Several` runs them as one.
+
 All times are `time.perf_counter()` seconds.
 """
 from __future__ import annotations
@@ -41,9 +45,10 @@ class Client:
         self.port = port
         self.conn = None
 
-    def post(self, user: str, jobs: list) -> int:
+    def post(self, user: str, jobs: list, pool: str = None) -> int:
+        extra = {} if pool is None else {"pool": pool}
         body = json.dumps({"jobs": [
-            {"uuid": u, "command": "true", "mem": m, "cpus": c}
+            {"uuid": u, "command": "true", "mem": m, "cpus": c, **extra}
             for u, _ui, m, c, _r in jobs]}).encode()
         for attempt in (0, 1):
             try:
@@ -90,8 +95,9 @@ class OpenLoop:
     """Sends a fixed schedule; `t0` anchors offset 0 (the window start)."""
 
     def __init__(self, port, schedule, stream, users, register, t0,
-                 workers: int):
+                 workers: int, pool: str = None):
         self.port = port
+        self.pool = pool
         self.users = users
         self.register = register
         self.workers = workers
@@ -130,7 +136,7 @@ class OpenLoop:
                     return
                 p, user, jobs = item
                 p.sent = time.perf_counter()
-                p.status = client.post(user, jobs)
+                p.status = client.post(user, jobs, self.pool)
                 p.done = time.perf_counter()
         finally:
             client.close()
@@ -149,8 +155,10 @@ class TopUp:
     one POST short of the target."""
 
     def __init__(self, port, stream, rng, users, register, base: int,
-                 launched, target: int, post_jobs: int, workers: int):
+                 launched, target: int, post_jobs: int, workers: int,
+                 pool: str = None):
         self.port = port
+        self.pool = pool
         self.stream = stream
         self.rng = rng
         self.users = users
@@ -198,7 +206,7 @@ class TopUp:
                     continue
                 p, user, jobs = item
                 p.sent = p.due
-                p.status = client.post(user, jobs)
+                p.status = client.post(user, jobs, self.pool)
                 p.done = time.perf_counter()
                 if p.status != 201:
                     with self._lock:
@@ -212,3 +220,28 @@ class TopUp:
         for t in self._threads:
             t.join(max(0.0, deadline - time.perf_counter()))
         return not any(t.is_alive() for t in self._threads)
+
+
+class Several:
+    """One generator per pool, started, stopped and read as one."""
+
+    def __init__(self, gens: list):
+        self.gens = gens
+
+    @property
+    def posts(self) -> list:
+        return [p for g in self.gens for p in g.posts]
+
+    def start(self):
+        for g in self.gens:
+            g.start()
+
+    def join(self, timeout: float) -> bool:
+        deadline = time.perf_counter() + timeout
+        return all([g.join(max(0.0, deadline - time.perf_counter()))
+                    for g in self.gens])
+
+    def stop(self, timeout: float) -> bool:
+        deadline = time.perf_counter() + timeout
+        return all([g.stop(max(0.0, deadline - time.perf_counter()))
+                    for g in self.gens])

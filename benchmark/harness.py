@@ -4,22 +4,29 @@ The cell's configuration and traffic are found by name in
 `benchmark/configs/` and `benchmark/traffic/`; its per-layer metrics by
 name in `benchmark/metrics/`. Nothing here names a cell.
 
-A run, in one process that owns the chip:
+A run, in one process that owns the chips:
 
-1. draws the backlog, the running set and the REST job stream from the
-   seed, and writes the backlog and running set into a fresh durable
-   event log (inside the checkout, on local disk);
+1. draws each pool's backlog, running set and REST job stream from the
+   seed (`workload.draw`), and writes the backlogs and running sets
+   into a fresh durable event log (inside the checkout, on local disk);
 2. boots the real scheduler in-process through `rest.server.serve`
    with the mock backend replaced by `backend.BenchCluster`; the
    restore path replays the log and the first resident build sees the
-   whole backlog; sets the configuration's default share through
-   `POST /share` and has the resident pool rebuilt, since rows built
-   at restore keep the share they were built with;
+   whole backlog; reads which chip each pool's resident state lives on
+   and stops unless the pools cover the cell's chips and no others, one
+   to a chip where the cell has several (`check_placement`); sets the configuration's default share
+   in each pool through `POST /share` and has each resident pool
+   rebuilt, since rows built at restore keep the share they were built
+   with;
 3. drives the cell's traffic for its warm-up seconds, then measures for
    `--seconds`; with `--trace 1` a middle part of the window is traced;
 4. after the window: follows the window's jobs through a drain while
    arrivals continue, reads peak device memory, stops the server, and
-   checks correctness (`oracle`).
+   checks correctness (`oracle`): `N_CAPTURES` cycles of every pool,
+   each judged against that pool's own jobs, hosts and share.
+
+A configuration without pools runs as one pool, the server's default,
+exactly as before pools existed.
 """
 from __future__ import annotations
 
@@ -86,11 +93,6 @@ def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
-
-
-def _rng(seed: int, tag: str):
-    return np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32,
-                                  workload._tag(tag)])
 
 
 class Capture:
@@ -162,22 +164,59 @@ ADMIN = "bench-admin"     # the operator who sets the default share
 
 
 def server_config(config: dict, log_path: str, port: int) -> dict:
-    return {"port": port, "log_path": log_path,
-            "auth": {"scheme": "header", "admins": [ADMIN]},
-            "scheduler": dict(config["scheduler"]),
-            "clusters": [{"kind": "mock", "name": s.cluster,
-                          "hosts": s.count, "host_mem": s.mem,
-                          "host_cpus": s.cpus}
-                         for s in workload.host_shapes(config)]}
+    """The server's configuration file for a cell. A configuration's
+    `default_pool`, `pools` and `federation` pass through unchanged,
+    and each mock cluster serves its host shape's `pool`; without them
+    the file is what it was before pools existed."""
+    clusters = []
+    for s in workload.host_shapes(config):
+        c = {"kind": "mock", "name": s.cluster, "hosts": s.count,
+             "host_mem": s.mem, "host_cpus": s.cpus}
+        if s.pool is not None:
+            c["pool"] = s.pool
+        clusters.append(c)
+    out = {"port": port, "log_path": log_path,
+           "auth": {"scheme": "header", "admins": [ADMIN]},
+           "scheduler": dict(config["scheduler"]),
+           "clusters": clusters}
+    for key in ("default_pool", "pools", "federation"):
+        if key in config:
+            out[key] = config[key]
+    return out
+
+
+class PlacementError(RuntimeError):
+    """The pools do not sit on the cell's chips as the cell asks."""
+
+
+def check_placement(placed: dict, chips: list) -> None:
+    """`placed` = {pool: [device id]}, `chips` = the ids of the cell's
+    chips. Every pool's state lies on the cell's chips and every chip
+    holds some; a cell of more than one chip holds one pool to a chip
+    (a cell of one chip holds every pool on it)."""
+    used = [d for ds in placed.values() for d in ds]
+    outside = set(used) - set(chips)
+    if outside:
+        raise PlacementError(f"pools on chips {sorted(outside)} outside "
+                             f"the cell's {sorted(chips)}: {placed}")
+    if len(chips) > 1 and len(used) != len(set(used)):
+        raise PlacementError(f"two pools share a chip: {placed}")
+    idle = set(chips) - set(used)
+    if idle:
+        raise PlacementError(f"{len(idle)} of the cell's {len(chips)} "
+                             f"chips hold no pool: {placed}")
 
 
 def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
              trace: bool, t_start: float, require_tpu: bool = True,
-             fault=None, save_dir=None, files=None) -> dict:
+             fault=None, fault_pool=None, save_dir=None,
+             files=None) -> dict:
     """One run. `files` = (config, traffic) in place of the cell's own
     files (tests at a small size); `fault` = fn(coordinator, resident
-    pool) called once the server is up (tests that break the timed
-    path); `require_tpu=False` skips the look for a chip (tests)."""
+    pool) called once the server is up with the resident pool of
+    `fault_pool` (None: a configuration without pools) (tests that
+    break the timed path); `require_tpu=False` skips the look for a
+    chip (tests)."""
     cell, config, traffic = find_cell(bench, cell_name, files)
     traffic = {**traffic, "runtime_s": config["task_runtime_s"]}
     import jax
@@ -191,13 +230,14 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     os.makedirs(work)
     try:
         return _run(bench, cell, config, traffic, seed, seconds, trace,
-                    t_start, devs, meter, work, fault, save_dir)
+                    t_start, devs, meter, work, fault, fault_pool,
+                    save_dir)
     finally:
         shutil.rmtree(work, ignore_errors=True)
 
 
 def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
-         devs, meter, work, fault, save_dir) -> dict:
+         devs, meter, work, fault, fault_pool, save_dir) -> dict:
     from cook_tpu import obs
     from cook_tpu.backends.base import LaunchSpec
     from cook_tpu.obs.metrics import registry
@@ -205,36 +245,35 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
     from benchmark import backend
 
     users = workload.user_names(config)
-    shapes = workload.host_shapes(config)
     # -- 1. the seeded store ------------------------------------------
-    pending = workload.JobStream(seed, "backlog", traffic,
-                                 len(users)).take(int(traffic["pending"]))
-    running = workload.JobStream(seed, "running", traffic,
-                                 len(users)).take(int(traffic["running"]))
-    rng = _rng(seed, "placement")
-    hosts = workload.place_running(rng, running, shapes)
-    task_ids = [f"seeded-{i:06d}" for i in range(len(running))]
-    remaining = dict(zip(task_ids, workload.residual_runtimes(
-        rng, len(running), config["task_runtime_s"]).tolist()))
+    draws = workload.draw(config, traffic, seed)
+    pools = [d.pool for d in draws]          # [None]: no pools
+    pending = [j for d in draws for j in d.pending]
+    running = [j for d in draws for j in d.running]
+    hosts = [h for d in draws for h in d.hosts]
+    task_ids = [t for d in draws for t in d.task_ids]
+    remaining = {t: r for d in draws for t, r in d.remaining.items()}
+    # uuid -> pool of every job the benchmark submits (None: no pools)
+    pool_of = {j[0]: d.pool for d in draws for j in d.pending + d.running}
     jobs = {j[0]: (users[j[1]], j[2], j[3], j[4])
             for j in pending + running}
-    cluster_of, caps = {}, {}
-    for s in shapes:
+    cluster_of, caps, caps_of = {}, {}, {p: {} for p in pools}
+    for s in workload.host_shapes(config):
         for i in range(s.count):
             h = f"{s.cluster}-host-{i}"
             cluster_of[h] = s.cluster
-            caps[h] = (s.mem, s.cpus)
+            caps[h] = caps_of[s.pool][h] = (s.mem, s.cpus)
     log_path = os.path.join(work, "events.log")
     seed_wall = time.time()
     workload.seed_store(log_path, users, pending, running, hosts,
-                        cluster_of, task_ids)
+                        cluster_of, task_ids, pool_of)
     seed_window = (int(seed_wall), int(time.time()))
     seed_specs: dict = {}
     for j, h, t in zip(running, hosts, task_ids):
         seed_specs.setdefault(cluster_of[h], []).append(
             LaunchSpec(task_id=t, job_uuid=j[0], hostname=h,
                        command="true", mem=j[2], cpus=j[3]))
-    ledger = backend.Ledger(jobs, {j[0] for j in running}, caps)
+    ledger = backend.Ledger(jobs, {j[0] for j in running}, caps, pool_of)
     ledger.task_job.update(zip(task_ids, (j[0] for j in running)))
 
     # -- 2. boot ------------------------------------------------------
@@ -253,47 +292,72 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
     served_stopped = False
     try:
         coord = served.coord
-        rp = coord._resident[coord.pools.default_pool]
+        rps = {p: coord._resident[coord.pools.default_pool if p is None
+                                  else p] for p in pools}
+        placed = {p: sorted(d.id for d in rp.devices())
+                  for p, rp in rps.items()}
+        names = {rps[p].pool: ids for p, ids in placed.items()}
+        print(f"diag: pool -> chip {json.dumps(names)}", file=sys.stderr)
+        check_placement(placed,
+                        [d.id for d in devs[:int(cell["chips"])]])
         if fault is not None:
-            fault(coord, rp)
-        caps_boot = (rp.Pcap, rp.Rcap, rp.Hcap)
-        cap = Capture(rp)
-        # the operator sets the default share; rows built at restore
-        # keep the share they were built with, so the pool rebuilds
-        _post_share(port, config["default_share"])
-        rp.request_resync()
+            fault(coord, rps[fault_pool])
+        caps_boot = {p: (rp.Pcap, rp.Rcap, rp.Hcap)
+                     for p, rp in rps.items()}
+        caps_taken = {p: Capture(rp) for p, rp in rps.items()}
+        # the operator sets the default share in each pool; rows built
+        # at restore keep the share they were built with, so each pool
+        # rebuilds
+        for p, rp in rps.items():
+            _post_share(port, config["default_share"], p)
+            rp.request_resync()
         t_share = time.perf_counter()
         # traffic starts once a cycle that began after the rebuild was
-        # asked for has dispatched (the second dispatch from now): the
-        # first cycle has compiled or loaded, so a cold run's compile
-        # cannot pile up a backlog that changes the shapes of later
-        # runs; the copies taken compile the capture's copy programs
-        cap.due = [t_share, t_share]
-        if not cap.wait_taken(2, 900):
-            raise RuntimeError("no match cycle was dispatched after boot")
-        cap.taken.clear()
+        # asked for has dispatched in every pool (the second dispatch
+        # from now): the first cycle has compiled or loaded, so a cold
+        # run's compile cannot pile up a backlog that changes the shapes
+        # of later runs; the copies taken compile the capture's copy
+        # programs
+        for cap in caps_taken.values():
+            cap.due = [t_share, t_share]
+        for cap in caps_taken.values():
+            if not cap.wait_taken(2, 900):
+                raise RuntimeError("no match cycle was dispatched after "
+                                   "boot")
+            cap.taken.clear()
 
-        def register(batch, user):
-            # a POST's jobs belong to the user who sends it
-            for u, _ui, m, c, r in batch:
-                jobs[u] = (user, m, c, r)
+        def registrar(pool):
+            def register(batch, user):
+                # a POST's jobs belong to the user who sends it, in the
+                # pool it names
+                for u, _ui, m, c, r in batch:
+                    jobs[u] = (user, m, c, r)
+                    pool_of[u] = pool
+            return register
 
         warm = float(traffic["warm_s"])
         drain = float(traffic["drain_s"])
-        stream = workload.JobStream(seed, "rest", traffic, len(users))
         t0 = time.perf_counter() + warm
-        if traffic["mode"] == "open":
-            sched = generator.open_schedule(
-                _rng(seed, "arrivals"), traffic, -warm, seconds + drain,
-                len(users))
-            gen = generator.OpenLoop(port, sched, stream, users, register,
-                                     t0, int(traffic["workers"]))
-        else:
-            gen = generator.TopUp(
-                port, stream, _rng(seed, "posts"), users, register,
-                len(pending), lambda: len(ledger.launched),
-                int(traffic["pending"]), int(traffic["post_jobs"]),
-                int(traffic["workers"]))
+        gens = []
+        for d in draws:
+            p = d.pool
+            stream = workload.JobStream(seed, "rest", traffic, len(users),
+                                        p)
+            if traffic["mode"] == "open":
+                sched = generator.open_schedule(
+                    workload.rng(seed, "arrivals", p), traffic, -warm,
+                    seconds + drain, len(users))
+                gens.append(generator.OpenLoop(
+                    port, sched, stream, users, registrar(p), t0,
+                    int(traffic["workers"]), p))
+            else:
+                gens.append(generator.TopUp(
+                    port, stream, workload.rng(seed, "posts", p), users,
+                    registrar(p), len(d.pending),
+                    lambda p=p: ledger.launched_in(p),
+                    int(traffic["pending"]), int(traffic["post_jobs"]),
+                    int(traffic["workers"]), p))
+        gen = generator.Several(gens)
         gen.start()
         backend.BenchCluster.clock_running.set()
         time.sleep(max(0.0, t0 - time.perf_counter()))
@@ -305,16 +369,19 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
         wall0 = time.time() * 1e3
         setup_s = t0 - t_start
         c0 = meter.snap()
-        pend0 = len(rp.pend_row)
+        pend0 = {p: len(rp.pend_row) for p, rp in rps.items()}
+        grow0 = _cap_grow(registry)
         ing0 = _hist_counts(registry.histogram("ingest_wait_ms"))
         n_rec0 = len(records)
-        crng = _rng(seed, "captures")
-        cap.due = sorted((t0 + seconds * crng.uniform(0.15, 0.85,
-                                                      N_CAPTURES)).tolist())
+        for p, cap in caps_taken.items():
+            crng = workload.rng(seed, "captures", p)
+            cap.due = sorted((t0 + seconds * crng.uniform(
+                0.15, 0.85, N_CAPTURES)).tolist())
         tracer = Tracer(t0, seconds, work) if trace else None
         time.sleep(seconds)
         t1 = time.perf_counter()
-        pend1 = len(rp.pend_row)
+        pend1 = {p: len(rp.pend_row) for p, rp in rps.items()}
+        grow1 = _cap_grow(registry)
         ing1 = _hist_counts(registry.histogram("ingest_wait_ms"))
         n_rec1 = len(records)
 
@@ -325,10 +392,12 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
         else:
             gen.stop(60)
         t_end = time.perf_counter()      # jobs are followed until here
-        got_caps = cap.wait_taken(N_CAPTURES, 30)
+        got_caps = {p: cap.wait_taken(N_CAPTURES, 30)
+                    for p, cap in caps_taken.items()}
         coord.drain_resident()
-        mem_peak = max(int((d.memory_stats() or {}).get(
-            "peak_bytes_in_use", 0)) for d in devs[:int(cell["chips"])])
+        mem_by_chip = {d.id: int((d.memory_stats() or {}).get(
+            "peak_bytes_in_use", 0)) for d in devs[:int(cell["chips"])]}
+        mem_peak = max(mem_by_chip.values())
         user_ids = dict(coord.interner.items())
         served.stop()
         served_stopped = True
@@ -338,14 +407,26 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
         window_recs = [r for r in records[n_rec0:n_rec1]
                        if wall0 <= r["t0_ms"] < wall0 + seconds * 1e3]
         n_compiles = meter.overlapping(t0, t1)
-        _diagnose(window_recs, meter, c0, n_compiles, rp, coord, t_start,
+        _diagnose(window_recs, meter, c0, n_compiles, coord, t_start,
                   t_seeded, t_booted, t0)
-        print(f"diag: caps at boot {caps_boot}; pending rows at window "
-              f"start {pend0}, end {pend1}; window from wall clock "
-              f"{wall0 / 1e3:.3f} s; captures at "
-              f"{[round(c['t'] - t0, 2) for c in cap.taken]} s",
-              file=sys.stderr)
+        launches = {p: sum(1 for t in ledger.pool_receipts.get(p, ())
+                           if t0 <= t < t1) for p in pools}
+        for p, rp in rps.items():
+            _diagnose_pool(window_recs, placed[p], caps_boot[p], rp,
+                           pend0[p], pend1[p], launches[p])
+        grew = {k: grow1[k] - grow0[k] for k in grow0}
+        taken_at = {rps[p].pool: [round(c["t"] - t0, 2) for c in cap.taken]
+                    for p, cap in caps_taken.items()}
+        print(f"diag: resident_cap_grow_total in the window {grew}; window "
+              f"from wall clock {wall0 / 1e3:.3f} s; captures at {taken_at} "
+              f"s", file=sys.stderr)
+        print(f"diag: memory peak per chip {mem_by_chip}", file=sys.stderr)
         tr = tracer.result(records) if tracer else None
+        if tr is not None:
+            print(f"diag: traced {tr['window_s']:.3f} s: device busy per "
+                  f"chip {tr['busy_by_chip']} s; match cycles begun in "
+                  f"it {tr['match_cycles_by_pool']}",
+                  file=sys.stderr)
         ctx = {"mode": traffic["mode"], "seconds": seconds,
                "records": window_recs,
                "compiles": n_compiles,
@@ -355,16 +436,19 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
         e2e, attempted, failed = _end_to_end(traffic, gen, ledger, t0, t1,
                                              seconds, t_end)
         e2e["setup_s"] = setup_s
-        truth = Truth(jobs, users, user_ids, caps, config["default_share"],
-                      seed_window, gen.posts, ledger)
-        cycles = [truth.cycle(c) for c in cap.taken] if got_caps else []
+        truth = Truth(jobs, users, user_ids, caps_of,
+                      config["default_share"], seed_window, gen.posts,
+                      ledger, pool_of)
+        cycles = [truth.cycle(c, p) for p, cap in caps_taken.items()
+                  if got_caps[p] for c in cap.taken]
         if save_dir:
             os.makedirs(save_dir, exist_ok=True)
             for k, (c, exp) in enumerate(cycles):
                 oracle.save_cycle(os.path.join(save_dir, f"cycle{k}.npz"),
                                   c, exp)
         acked = [u for p in gen.posts if p.status == 201 for u in p.uuids]
-        compared = correctness(cycles, N_CAPTURES, ledger, log_path, acked)
+        compared = correctness(cycles, N_CAPTURES * len(pools), ledger,
+                               log_path, acked)
         section = "per_layer" if trace else "end_to_end"
         metrics = {}
         for m in metric_names(bench, cell["name"], section):
@@ -382,7 +466,8 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
             device["window_s"] = tr["window_s"]
             out["breakdown"] = {"device_ops": tr["device_ops"],
                                 "idle_gaps": tr["idle_gaps"]}
-        out["samples"] = e2e.pop("samples")
+        out["samples"] = {**e2e.pop("samples"),
+                          "cycles_checked": len(cycles)}
         out["compared"] = {k: {"value": v, "limit": lim}
                            for k, (v, lim) in compared.items()}
         return out
@@ -391,13 +476,17 @@ def _run(bench, cell, config, traffic, seed, seconds, trace, t_start,
             served.stop()
 
 
-def _post_share(port: int, share: dict) -> None:
-    """POST /share for the default user, as the deployment's operator."""
+def _post_share(port: int, share: dict, pool=None) -> None:
+    """POST /share for the default user in `pool` (None: the server's
+    default pool), as the deployment's operator."""
+    body = {"user": "default", "share": share}
+    if pool is not None:
+        body["pool"] = pool
     conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
     try:
-        conn.request("POST", "/share", body=json.dumps(
-            {"user": "default", "share": share}).encode(), headers={
-                "Content-Type": "application/json", "X-Cook-User": ADMIN})
+        conn.request("POST", "/share", body=json.dumps(body).encode(),
+                     headers={"Content-Type": "application/json",
+                              "X-Cook-User": ADMIN})
         resp = conn.getresponse()
         body = resp.read()
     finally:
@@ -424,12 +513,10 @@ def _settle(coord, settle_s: float, give_up_s: float = 90.0) -> None:
             return
 
 
-def _diagnose(recs, meter, c0, n_compiles, rp, coord, t_start, t_seeded,
+def _diagnose(recs, meter, c0, n_compiles, coord, t_start, t_seeded,
               t_booted, t0) -> None:
     """Where the set-up went and what the window's cycles looked like
     (stderr, before the compared numbers)."""
-    def mean(xs):
-        return sum(xs) / len(xs) if xs else float("nan")
     match = [r["wall_ms"] for r in recs if r["kind"] == "match"]
     cons = [r for r in recs if r["kind"] == "consume"]
     matched = [(r.get("attrs") or {}).get("matched", 0) for r in cons]
@@ -441,11 +528,40 @@ def _diagnose(recs, meter, c0, n_compiles, rp, coord, t_start, t_seeded,
           f"the window {n_compiles}; slow compiles "
           f"{meter.slow}", file=sys.stderr)
     print(f"diag: window: {len(match)} match cycles, mean wall "
-          f"{mean(match):.3f} ms; {len(cons)} consumes, mean wall "
-          f"{mean([r['wall_ms'] for r in cons]):.3f} ms, mean matched "
-          f"{mean(matched):.1f}; overload level {level}; caps Pcap "
-          f"{rp.Pcap} Rcap {rp.Rcap} Hcap {rp.Hcap}; pending rows "
-          f"{len(rp.pend_row)}", file=sys.stderr)
+          f"{_mean(match):.3f} ms; {len(cons)} consumes, mean wall "
+          f"{_mean([r['wall_ms'] for r in cons]):.3f} ms, mean matched "
+          f"{_mean(matched):.1f}; overload level {level}", file=sys.stderr)
+
+
+def _diagnose_pool(recs, chips, caps_boot, rp, pend0, pend1,
+                   launches) -> None:
+    """One pool's line: its chip, its caps at boot and at the end and
+    the cycles of the window that changed them, its pending rows, its
+    launches and cycles in the window."""
+    name = rp.pool
+    mine = [r for r in recs if r.get("pool") == name]
+    match = [r for r in mine if r["kind"] == "match"]
+    cons = [r for r in mine if r["kind"] == "consume"]
+    grew = [{k: v for k, v in (r.get("attrs") or {}).items()
+             if k in ("Pcap", "Rcap", "Hcap", "resync")}
+            for r in match if "resync" in (r.get("attrs") or {})]
+    print(f"diag: pool {name}: chip {chips}; caps (Pcap, Rcap, Hcap) at "
+          f"boot {caps_boot}, at the end {(rp.Pcap, rp.Rcap, rp.Hcap)}, "
+          f"changed in the window {grew}; pending rows at window start "
+          f"{pend0}, end {pend1}; launches in the window {launches}; "
+          f"{len(match)} match cycles, mean wall "
+          f"{_mean([r['wall_ms'] for r in match]):.3f} ms; {len(cons)} "
+          f"consumes, mean wall {_mean([r['wall_ms'] for r in cons]):.3f}"
+          f" ms", file=sys.stderr)
+
+
+def _mean(xs) -> float:
+    return sum(xs) / len(xs) if xs else float("nan")
+
+
+def _cap_grow(registry) -> dict:
+    return {k: registry.counter("resident_cap_grow_total", cap=k).value
+            for k in ("Pcap", "Rcap", "Hcap")}
 
 
 def _hist_counts(h) -> list:
@@ -472,7 +588,9 @@ def _hist_delta(a: list, b: list) -> list:
 
 class Tracer:
     """Traces the middle of the window on a thread of its own; the
-    reduction runs after the window."""
+    reduction runs after the window. The wall clock at the trace's
+    start only counts the match cycles that began in the traced part;
+    idle gaps are named from the trace's own host events."""
 
     def __init__(self, t0: float, seconds: float, work: str):
         self.a, self.b = t0 + 0.35 * seconds, t0 + 0.65 * seconds
@@ -504,17 +622,18 @@ class Tracer:
         self._th.join()
         if self.error is not None:
             raise self.error
-        phases = []
-        for r in records:
-            for ph in r["phases"]:
-                lo = r["t0_ms"] + ph["off_ms"]
-                phases.append((f"{r['kind']}.{ph['name']}", lo,
-                               lo + ph["ms"]))
-        res = tracing.reduce(tracing.load(tracing.find_xplane(
-            self.log_dir)), self.wall, phases)
+        from benchmark import scopes
+        path = tracing.find_xplane(self.log_dir)
+        res = tracing.reduce(tracing.load(path))
+        res["device_ops"] = scopes.op_times(path)[:10]
+        res["idle_gaps"] = [[label, sec] for label, sec, _beside in
+                            scopes.label_gaps(path, res.pop("gaps"))]
         w1 = self.wall + res["window_s"] * 1e3
-        res["match_cycles"] = sum(1 for r in records if r["kind"] == "match"
-                                  and self.wall <= r["t0_ms"] < w1)
+        traced = [r["pool"] for r in records if r["kind"] == "match"
+                  and self.wall <= r["t0_ms"] < w1]
+        res["match_cycles"] = len(traced)
+        res["match_cycles_by_pool"] = {p: traced.count(p)
+                                       for p in sorted(set(traced))}
         return res
 
 
@@ -551,23 +670,25 @@ def _end_to_end(traffic, gen, ledger, t0, t1, seconds, t_end):
 
 class Truth:
     """What the benchmark itself submitted and configured, in the shape
-    the oracle compares a captured cycle with (`expected`): for every
-    pending and running row of the capture, the job the program's row
-    map names, as the benchmark drew it, and the wall seconds within
-    which the program stamped its submit or start; the benchmark's user
-    behind each of the program's user ids; each host's configured
-    capacity; the default share."""
+    the oracle compares a captured cycle of one pool with (`expected`):
+    for every pending and running row of the capture, the job the
+    program's row map names, if the benchmark submitted it to that
+    pool, as the benchmark drew it, and the wall seconds within which
+    the program stamped its submit or start; the benchmark's user behind
+    each of the program's user ids; the capacity of each host the
+    configuration gives the pool; the configuration's default share."""
 
     START_BEFORE_S = 10     # a launch's instance is created this far
     #                         at most before the backend receives it
 
-    def __init__(self, jobs, users, user_ids, caps, share, seed_window,
-                 posts, ledger):
+    def __init__(self, jobs, users, user_ids, caps_of, share,
+                 seed_window, posts, ledger, pool_of):
         self.jobs = jobs
         self.user_index = {u: i for i, u in enumerate(users)}
         self.user_ids = user_ids
-        self.caps = caps
-        self.share = np.array([share["mem"], share["cpus"]], np.float64)
+        self.caps_of = caps_of          # pool -> {host: (mem, cpus)}
+        self.share = share              # {"mem", "cpus"}, every pool
+        self.pool_of = pool_of          # uuid -> pool (None: no pools)
         self.seed_window = seed_window
         self.ledger = ledger
         off = time.time() - time.perf_counter()
@@ -579,13 +700,13 @@ class Truth:
                 self.posted[u] = w
         self.off = off
 
-    def _rows(self, n, pairs, window):
+    def _rows(self, n, pairs, window, pool):
         e = {"known": np.zeros(n, bool), "user": np.full(n, -1, np.int64),
              "mem": np.zeros(n), "cpus": np.zeros(n),
              "lo_s": np.zeros(n, np.int64), "hi_s": np.zeros(n, np.int64)}
         for row, u, key in pairs:
             j = self.jobs.get(u)
-            if j is None:
+            if j is None or self.pool_of.get(u) != pool:
                 continue
             e["known"][row] = True
             e["user"][row] = self.user_index.get(j[0], -1)
@@ -602,8 +723,9 @@ class Truth:
         wall = int(got + self.off)
         return (wall - self.START_BEFORE_S, wall + 1)
 
-    def cycle(self, c):
-        """(capture, expected) of one taken cycle, read back."""
+    def cycle(self, c, pool=None):
+        """(capture, expected) of one taken cycle of `pool` (None: a
+        configuration without pools), read back."""
         import jax
         st = jax.device_get(c["state"])
         cap = {"pend": st["pend"], "run": st["run"], "host": st["host"],
@@ -617,27 +739,30 @@ class Truth:
         pend_pairs = [(row, u, None) for row, u in enumerate(c["row_uuid"])
                       if u is not None]
         pend = self._rows(P, pend_pairs, lambda u, _k: self.posted.get(
-            u, self.seed_window))
+            u, self.seed_window), pool)
         launched = self.ledger.launched
         pend["launched"] = np.zeros(P, bool)
         for row, u, _k in pend_pairs:
             pend["launched"][row] = launched.get(u, c["t"]) < c["t"]
         run = self._rows(R, [(row, self.ledger.task_job.get(t), t)
                              for t, row in c["run_row"].items()],
-                         self._started)
+                         self._started, pool)
+        caps = self.caps_of[pool]
         host = {"known": np.zeros(H, bool), "cap_mem": np.zeros(H),
                 "cap_cpus": np.zeros(H)}
         for row, name in enumerate(c["host_names"]):
-            if name in self.caps:
+            if name in caps:
                 host["known"][row] = True
-                host["cap_mem"][row], host["cap_cpus"][row] = \
-                    self.caps[name]
+                host["cap_mem"][row], host["cap_cpus"][row] = caps[name]
         uid = np.full(cap["qm"].shape[0], -1, np.int64)
         for name, i in self.user_ids.items():
             if 0 <= i < uid.shape[0]:
                 uid[i] = self.user_index.get(name, -1)
+        share = self.share
         return cap, {"pend": pend, "run": run, "host": host,
-                     "user_of_id": uid, "share": self.share}
+                     "user_of_id": uid,
+                     "share": np.array([share["mem"], share["cpus"]],
+                                       np.float64)}
 
 
 def correctness(cycles, n_due, ledger, log_path, acked) -> dict:

@@ -244,6 +244,22 @@ def scope_times(path: str) -> dict:
     return {s: ns / 1e9 / len(planes) for s, ns in total.items()}
 
 
+def op_times(path: str) -> list:
+    """[["scope/op", seconds]] of every device op inside the marker,
+    summed over the TPU planes, longest first; `unscoped/op` for an op
+    no scope claims (`attributed`)."""
+    m0, m1 = tracing.load(path)["marker"]
+    total: dict = {}
+    for ops in device_ops(path).values():
+        for (a, b, _t, name), (_a, _b, s) in zip(ops, attributed(ops)):
+            if b > m0 and a < m1:
+                key = f"{s}/{name}"
+                total[key] = total.get(key, 0.0) + (min(b, m1)
+                                                    - max(a, m0)) / 1e9
+    return [[k, v] for k, v in sorted(total.items(),
+                                      key=lambda kv: -kv[1])]
+
+
 def host_events(path: str) -> list:
     """(thread, label, start_ns, end_ns) of the program's own host
     events: cycle records as `kind.phase` (`kind` for a segment no
@@ -287,16 +303,9 @@ def label_gaps(path: str, gaps) -> list:
 
 
 def idle_gaps(path: str, n: int = 10) -> list:
-    """The `n` longest idle gaps of the first TPU plane inside the
-    marker, labelled (`label_gaps`)."""
-    t = tracing.load(path)
-    m0, m1 = t["marker"]
-    name = sorted(t["planes"])[0]
-    u = _clipped_union([(a, b) for a, b, _n in t["planes"][name]], m0, m1)
-    edges = [m0] + [x for ab in u for x in ab] + [m1]
-    gaps = sorted(((a, b) for a, b in zip(edges[0::2], edges[1::2])
-                   if b > a), key=lambda g: g[0] - g[1])[:n]
-    return label_gaps(path, gaps)
+    """The `n` longest idle gaps of any TPU plane inside the marker,
+    labelled (`label_gaps`)."""
+    return label_gaps(path, tracing.reduce(tracing.load(path), n)["gaps"])
 
 
 # -- the run in progress -----------------------------------------------

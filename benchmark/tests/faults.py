@@ -1,11 +1,13 @@
 """Faults planted in the timed path, for test_faults.py.
 
-    python3 benchmark/tests/faults.py <fault> <seed>
+    python3 benchmark/tests/faults.py <fault> <seed> [<data file> [<chips>]]
 
-Runs the harness on the CPU at the tiny size of data/tiny.json (no look
-for a chip) with the named fault installed once the server is up, and
-prints the result line. Each fault breaks what the device cycle hands
-to the rest of the served path:
+Runs the harness on the CPU at a tiny size (data/tiny.json unless
+another file under data/ is named; no look for a chip; the cell asks
+for `chips`, 1 unless given) with the named fault installed once the
+server is up in the configuration's last pool only, and prints the
+result line. Each fault breaks what the device cycle hands to the rest
+of the served path:
 
 - `none`: nothing broken;
 - `state_unchanged`: each cycle returns the resident state it was given
@@ -90,22 +92,27 @@ FAULTS = {"none": None, "state_unchanged": state_unchanged,
           "altered_answer": altered_answer}
 
 
-def main(fault: str, seed: int) -> int:
+def main(fault: str, seed: int, data: str = "tiny.json",
+         chips: int = 1) -> int:
     sys.path.insert(0, ROOT)
-    from benchmark import harness
+    from benchmark import harness, workload
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    with open(os.path.join(os.path.dirname(__file__), "data",
-                           "tiny.json")) as f:
+    with open(os.path.join(os.path.dirname(__file__), "data", data)) as f:
         tiny = json.load(f)
-    out = harness.run_cell(bench, "default-steady", seed, 4.0, False,
-                           T_START, require_tpu=False, fault=FAULTS[fault],
-                           files=(tiny["config"], tiny["traffic"]))
+    # default-steady's entry, asking for `chips`, runs the tiny files
+    bench["workloads"] = [dict(w, chips=chips) for w in bench["workloads"]]
+    out = harness.run_cell(
+        bench, "default-steady", seed, 4.0, False, T_START,
+        require_tpu=False, fault=FAULTS[fault],
+        fault_pool=workload.pool_names(tiny["config"])[-1],
+        files=(tiny["config"], tiny["traffic"]))
     print(json.dumps(out), flush=True)
     return 0
 
 
 if __name__ == "__main__":
-    code = main(sys.argv[1], int(sys.argv[2]))
+    code = main(sys.argv[1], int(sys.argv[2]), *sys.argv[3:4],
+                *[int(c) for c in sys.argv[4:5]])
     sys.stdout.flush()
     os._exit(code)

@@ -56,6 +56,22 @@ def test_plain_trace_is_all_unscoped():
     assert t["unscoped"] == t["busy"] > 0
 
 
+def test_device_ops_are_named_scope_slash_op():
+    ops = scopes.op_times(SCOPED)
+    secs = [s for _n, s in ops]
+    assert secs == sorted(secs, reverse=True)
+    by_scope: dict = {}
+    for name, s in ops:
+        scope, op = name.split("/", 1)
+        assert op and " = " not in op
+        by_scope[scope] = by_scope.get(scope, 0.0) + s
+    # an op's own time is at least its share of its scope's union
+    t = scopes.scope_times(SCOPED)
+    for scope in ("rank", "head_scan"):
+        assert by_scope[scope] >= t[scope] - 1e-12
+    assert {"rank", "head_scan", "unscoped"} <= set(by_scope)
+
+
 def test_scope_times_match_the_tf_op_grouping():
     t = scopes.scope_times(SCOPED)
     m0, m1 = tracing.load(SCOPED)["marker"]

@@ -6,9 +6,8 @@ not depend on any executable's name. A trace without a TPU plane, or a
 plane without ops, is an error, never an empty result.
 
 The traced window is the host annotation `MARKER` the harness wraps
-around it; its start, taken on the host's wall clock just before the
-annotation opened, aligns the trace with the program's cycle records
-so that each idle gap can be labelled with what the host was doing.
+around it. Idle gaps are returned as intervals on the trace's clock;
+`scopes.label_gaps` names them from the host events of the same trace.
 """
 from __future__ import annotations
 
@@ -73,38 +72,22 @@ def load(path: str) -> dict:
     return {"planes": planes, "marker": marker}
 
 
-def reduce(trace: dict, marker_wall_ms: float, phases=()) -> dict:
-    """busy_s (mean over chips), window_s, the longest ops by total
-    time and the longest idle gaps, each gap labelled with the host
-    phases (`kind.phase` of the program's cycle records, wall-clock
-    ms intervals) that cover its midpoint."""
+def reduce(trace: dict, n_gaps: int = 10) -> dict:
+    """busy_s (mean over chips), busy seconds per chip (`busy_by_chip`,
+    by plane name), window_s, and the `n_gaps` longest idle gaps of any
+    chip inside the marker, as (start_ns, end_ns)."""
     m0, m1 = trace["marker"]
-    busy, ops, gaps = [], {}, []
-    for name, evs in sorted(trace["planes"].items()):
-        clipped = [(max(a, m0), min(b, m1)) for a, b, _n in evs
-                   if b > m0 and a < m1]
-        for a, b, n in evs:
-            if b > m0 and a < m1:
-                ops[n] = ops.get(n, 0.0) + (min(b, m1) - max(a, m0)) / 1e9
-        u = _union(clipped)
+    busy, gaps = [], []
+    names = sorted(trace["planes"])
+    for name in names:
+        evs = trace["planes"][name]
+        u = _union([(max(a, m0), min(b, m1)) for a, b, _n in evs
+                    if b > m0 and a < m1])
         busy.append(sum(b - a for a, b in u) / 1e9)
         edges = [m0] + [x for ab in u for x in ab] + [m1]
-        for a, b in zip(edges[0::2], edges[1::2]):
-            if b > a:
-                gaps.append((a, b))
+        gaps += [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
     gaps.sort(key=lambda g: g[0] - g[1])
-
-    def wall(ns):
-        return marker_wall_ms + (ns - m0) / 1e6
-
-    def label(a, b):
-        mid = wall((a + b) / 2)
-        names = sorted({n for n, lo, hi in phases if lo <= mid < hi})
-        return "+".join(names) if names else "no cycle phase"
-
     return {"busy_s": sum(busy) / len(busy),
+            "busy_by_chip": dict(zip(names, busy)),
             "window_s": (m1 - m0) / 1e9,
-            "device_ops": [[n, s] for n, s in
-                           sorted(ops.items(), key=lambda kv: -kv[1])[:10]],
-            "idle_gaps": [[label(a, b), (b - a) / 1e9]
-                          for a, b in gaps[:10]]}
+            "gaps": gaps[:n_gaps]}
