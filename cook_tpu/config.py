@@ -146,11 +146,6 @@ class SchedulerSettings:
     # acknowledged RUNNING within this window fails 5003 (mea-culpa)
     # and requeues; must exceed the worst honest fetch+start time
     launch_ack_timeout_s: float = 300.0
-    # async consume executor: how many keyed in-order workers drain
-    # matched prefixes (cycle consume/launch). Each pool's work stays
-    # on one worker (per-pool ordering preserved); multiple pools
-    # drain concurrently. 1 = the old single shared consumer thread.
-    consume_workers: int = 4
     # parallel agent fan-out (backends/agent.py): a launch batch that
     # spans K hosts ships as K concurrent POSTs on a bounded executor
     # instead of a serial per-host loop; per-host ordering holds (one
@@ -198,8 +193,6 @@ class SchedulerSettings:
             raise ConfigError("max_jobs_considered must be >= 1")
         if self.launch_ack_timeout_s <= 0:
             raise ConfigError("launch_ack_timeout_s must be > 0")
-        if self.consume_workers < 1:
-            raise ConfigError("consume_workers must be >= 1")
         if self.launch_fanout_workers < 1:
             raise ConfigError("launch_fanout_workers must be >= 1 "
                               "(1 = serial per-host launch)")

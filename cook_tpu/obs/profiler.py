@@ -164,13 +164,17 @@ class CycleRec:
     be too.  Only :meth:`CycleProfiler.commit` is gated on enablement.
     """
 
-    __slots__ = ("kind", "pool", "cycle", "t0", "t0_ms", "_c0", "_last",
-                 "_clast", "phases", "children", "attrs", "_ta", "_ann")
+    __slots__ = ("kind", "pool", "cycle", "thread", "t0", "t0_ms", "_c0",
+                 "_last", "_clast", "phases", "children", "attrs", "_ta",
+                 "_ann")
 
     def __init__(self, kind: str, pool: str, cycle: Optional[int] = None):
         self.kind = kind
         self.pool = pool
         self.cycle = cycle
+        # the thread that runs the cycle (a pool's match loop or
+        # consume worker)
+        self.thread = threading.current_thread().name
         self.t0 = time.perf_counter()
         self.t0_ms = time.time() * 1e3
         self._c0 = time.thread_time()
@@ -365,7 +369,7 @@ class CycleProfiler:
                            "off_ms": round((a - rec.t0) * 1e3, 4)})
             if name not in OVERLAP_PHASES and ms > crit_ms:
                 crit_name, crit_ms = name, ms
-        entry = {"kind": rec.kind, "pool": rec.pool,
+        entry = {"kind": rec.kind, "pool": rec.pool, "thread": rec.thread,
                  "t0_ms": round(rec.t0_ms, 3),
                  "wall_ms": round(wall_ms, 4),
                  "cpu_ms": round(cpu_ms, 4),

@@ -445,7 +445,6 @@ def build_scheduler(config, read_only=False):
             use_pallas=_resolve_use_pallas(s.use_pallas,
                                            s.max_jobs_considered),
             launch_ack_timeout_s=s.launch_ack_timeout_s,
-            consume_workers=s.consume_workers,
             pipeline_depth=s.pipeline_depth,
             decision_provenance=s.decision_provenance,
             heartbeat_timeout_s=s.heartbeat_timeout_s),

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json
 import logging
+import queue
 import threading
 import time
 from dataclasses import dataclass, field
@@ -109,12 +110,6 @@ class SchedulerConfig:
     # honest fetch+start time (image pulls, uri downloads); reconcile()
     # can't cover this case because it only resyncs RUNNING instances
     launch_ack_timeout_s: float = 300.0
-    # async consume executor width: keyed in-order workers draining
-    # matched prefixes (readback -> launch txn -> backend hand-off).
-    # One pool's cycles always land on the same worker (ordering), but
-    # different pools drain concurrently instead of serializing on the
-    # single consumer thread this replaced.
-    consume_workers: int = 4
     # resident pipeline depth: cycles allowed in flight between
     # dispatch and consume. Sync pools double-/multi-buffer on the
     # cycle thread itself (cycle N+1 matches on device while cycle N's
@@ -213,6 +208,11 @@ class Coordinator:
         self._adaptive_head: dict[str, AdaptiveHead] = {}
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
+        # run()'s per-pool match loops: pool -> (thread, halt event).
+        # A pool being retired keeps its entry, halted, until its state
+        # is gone, so no loop starts for it meanwhile.
+        self._match_loops: dict = {}
+        self._match_loops_lock = threading.Lock()
         # restart-reconciliation gate: set = match cycles may run. Open
         # by default (tests/simulator drive cycles directly); the
         # server arms it before run() so the first post-restore cycle
@@ -338,6 +338,9 @@ class Coordinator:
         # EWMA pause predictions per rung, seeded pessimistically so
         # the first gen-1/gen-2 passes wait for an idle window
         self._refreeze_pred_ms = [1.0, 10.0, 0.0]
+        # the pools' match loops share the cadence and the predictions:
+        # one loop refreezes at a time, the others skip the tick
+        self._refreeze_lock = threading.Lock()
         # hash-sharded in-order status executors
         # (async-in-order-processing scheduler.clj:1524-1546): backend
         # callbacks enqueue and return instead of running the store
@@ -565,7 +568,6 @@ class Coordinator:
         rp = ResidentPool(self, pool, synchronous=synchronous, **kw)
         self._resident[pool] = rp
         if not synchronous:
-            import queue
             # per-pool launcher thread: the consumer hands each cycle's
             # per-cluster specs over and moves straight to the next
             # readback — the backend hand-off (HTTP posts, mock Python)
@@ -580,7 +582,6 @@ class Coordinator:
                                  name=f"resident-launcher-{pool}")
             t.start()
             self._threads.append(t)
-        if not synchronous:
             # per-pool consume backpressure (the role the old shared
             # maxsize=2 queue played, now per pool): at most
             # max(2, pipeline_depth) cycles outstanding between
@@ -589,17 +590,16 @@ class Coordinator:
             # blocking (2 stays the floor: it is the minimum overlap)
             rp._consume_slots = threading.BoundedSemaphore(
                 max(2, rp.pipeline_depth))
-        if not synchronous and getattr(self, "_consume_shards",
-                                       None) is None:
-            # keyed in-order consume executor: cycles of ONE pool stay
-            # on one worker (per-pool ordering — launch txns of cycle N
-            # commit before cycle N+1's), while different pools drain
-            # concurrently instead of serializing on a single consumer
-            # thread
-            from cook_tpu.scheduler.shards import InOrderShards
-            self._consume_shards = InOrderShards(
-                max(1, self.config.consume_workers),
-                self._consume_one, name="resident-consumer")
+            # per-pool consume worker: the pool's cycles are consumed in
+            # order on it (launch txns of cycle N commit before cycle
+            # N+1's), and no two pools share one, so pools drain
+            # concurrently
+            rp._consume_q = queue.Queue()
+            c = threading.Thread(target=self._consume_loop,
+                                 args=(pool, rp), daemon=True,
+                                 name=f"resident-consumer-{pool}")
+            c.start()
+            self._threads.append(c)
 
     def retire_resident(self, pool: str) -> bool:
         """Drain and retire one pool's resident state: in-flight cycles
@@ -611,12 +611,27 @@ class Coordinator:
         prev = getattr(self, "_resident", {}).get(pool)
         if prev is None:
             return False
-        prev.enabled = False
-        self.drain_resident(pool)   # in-flight consumed, queue empty
-        q = getattr(prev, "_launch_q", None)
-        if q is not None:
-            q.put(None)    # retire the thread
-        self._resident.pop(pool, None)
+        # the pool's match loop exits before its state goes; its halted
+        # entry stays until then, so none starts meanwhile. A pool still
+        # active then gets a new loop at the next _sync_match_loops.
+        with self._match_loops_lock:
+            t, halt = self._match_loops.setdefault(
+                pool, (None, threading.Event()))
+            halt.set()
+        if t is not None and t is not threading.current_thread():
+            t.join()    # its cycle in flight, if any, has returned
+        try:
+            prev.enabled = False
+            self.drain_resident(pool)   # in-flight consumed, queue empty
+            for name in ("_launch_q", "_consume_q"):
+                q = getattr(prev, name, None)
+                if q is not None:
+                    q.put(None)    # retire the thread
+            self._resident.pop(pool, None)
+        finally:
+            with self._match_loops_lock:
+                self._match_loops.pop(pool, None)
+                _count_loops(self._match_loops)
         return True
 
     # store event kinds whose payload names the owning job directly
@@ -697,12 +712,24 @@ class Coordinator:
             finally:
                 rp._launch_q.task_done()
 
-    def _consume_one(self, pool: str, rp, out) -> None:
-        """Consume-shard handler: one cycle's readback + launch txn +
-        backend hand-off, releasing the pool's backpressure slot when
-        done (success or failure)."""
+    def _consume_loop(self, pool: str, rp) -> None:
+        """One pool's consume worker: the cycles its match loop hands
+        over, in order, until retire_resident or stop puts None."""
+        while True:
+            item = rp._consume_q.get()
+            if item is None:
+                return
+            out, t_handoff = item
+            self._consume_one(pool, rp, out,
+                              (time.perf_counter() - t_handoff) * 1e3)
+
+    def _consume_one(self, pool: str, rp, out, queued_ms: float) -> None:
+        """One cycle's readback + launch txn + backend hand-off on the
+        pool's consume worker, releasing the pool's backpressure slot
+        when done (success or failure). `queued_ms`: the time from the
+        match loop's hand-off to this start."""
         try:
-            self._consume_cycle(pool, rp, out)
+            self._consume_cycle(pool, rp, out, queued_ms)
         except Exception:
             # the device already depleted this cycle's matched
             # capacity and invalidated the matched rows; without a
@@ -952,7 +979,7 @@ class Coordinator:
             # backpressure in the cycle wall
             with rec.phase("queue_wait") as ph_q:
                 rp._consume_slots.acquire()
-                self._consume_shards.submit(pool, pool, rp, out)
+                rp._consume_q.put((out, time.perf_counter()))
             self.metrics[f"match.{pool}.queue_wait_ms"] = ph_q.ms
             last = rp.stats_last
             if last is not None:
@@ -996,11 +1023,18 @@ class Coordinator:
                             matched=stats.matched)
         return stats
 
-    def _consume_cycle(self, pool: str, rp, out) -> dict:
+    def _consume_cycle(self, pool: str, rp, out,
+                       queued_ms: Optional[float] = None) -> dict:
         """Block on one cycle's compact readback, run the bulk launch
-        transaction, hand specs to the backends. Returns cycle stats."""
+        transaction, hand specs to the backends. Returns cycle stats.
+        `queued_ms` (async pools): how long the cycle waited for the
+        pool's consume worker, kept as an attr of the consume record."""
         import jax
         rec = obs.profiler.cycle("consume", pool, out.cycle_no)
+        if queued_ms is not None:
+            rec.set_attr("queued_ms", round(queued_ms, 4))
+            metrics_registry.histogram(
+                "consume_queued_ms", pool=pool).observe(queued_ms)
         # scalars first: 3 values tell us exactly how much else to pull
         head_matched, n_matched, n_considerable = jax.device_get(
             (out.head_matched, out.n_matched, out.n_considerable))
@@ -1802,6 +1836,14 @@ class Coordinator:
         cap (bounded by the forced full-rung cadence) for bounded,
         chosen pauses. gc_refreeze_budget_ms <= 0 restores the legacy
         unconditional full pass."""
+        if not self._refreeze_lock.acquire(blocking=False):
+            return      # another pool's loop is refreezing
+        try:
+            self._refreeze(cycle_ms)
+        finally:
+            self._refreeze_lock.release()
+
+    def _refreeze(self, cycle_ms: float) -> None:
         now = time.monotonic()
         if now < self._next_refreeze:
             return
@@ -2536,21 +2578,12 @@ class Coordinator:
         reference's deposed leader suicides and Datomic's single
         transactor refuses it anyway)."""
         self._leadership_check = leadership_check
-        def loop(interval, fn, per_pool=True, gate=None, wait_name=None):
-            wait = self._stop.wait
-            if wait_name is not None:
-                def wait(s, _wait=self._stop.wait):
-                    # the sleep between ticks, named in a device trace
-                    with obs.annotation(wait_name):
-                        return _wait(s)
-
+        def loop(interval, fn, per_pool=True):
             def body():
-                while not wait(interval):
+                while not self._stop.wait(interval):
                     try:
                         if leadership_check is not None \
                                 and not leadership_check():
-                            continue
-                        if gate is not None and not gate():
                             continue
                         if per_pool:
                             for p in self.active_pools():
@@ -2563,8 +2596,14 @@ class Coordinator:
             t.start()
             self._threads.append(t)
 
-        loop(self.config.match_interval_s, self.match_cycle,
-             gate=self._match_gate, wait_name="match.tick_wait")
+        # one match loop per active pool, as Cook runs one per pool
+        # (scheduler.clj:1557-1578): a pool whose consumer lags blocks
+        # only its own loop. The set follows active_pools(): loops
+        # start for pools that join it and exit for pools that leave.
+        def sync():
+            self._sync_match_loops(leadership_check)
+        sync()
+        loop(self.MATCH_LOOP_SCAN_S, sync, per_pool=False)
         loop(self.config.rebalancer_interval_s, self.rebalance_cycle)
         loop(60.0, self.watchdog_cycle, per_pool=False)
         opt = getattr(self, "optimizer_cycle", None)
@@ -2585,17 +2624,73 @@ class Coordinator:
             # the shed ladder at most one rung per evaluation
             loop(2.0, self.overload.evaluate, per_pool=False)
 
+    # how often run() starts a match loop for each active pool without
+    # one (a loop exits by itself when its pool leaves the set)
+    MATCH_LOOP_SCAN_S = 0.5
+
+    def _sync_match_loops(self, lc: Optional[Callable[[], bool]]) -> None:
+        """Start a match loop for every active pool without one."""
+        with self._match_loops_lock:
+            if self._stop.is_set():
+                return
+            for p in self.active_pools():
+                if p.name in self._match_loops:
+                    continue
+                halt = threading.Event()
+                t = threading.Thread(
+                    target=self._match_loop, args=(p.name, halt, lc),
+                    daemon=True, name=f"match-{p.name}")
+                self._match_loops[p.name] = (t, halt)
+                t.start()
+            _count_loops(self._match_loops)
+
+    def _match_loop(self, pool: str, halt: threading.Event,
+                    lc: Optional[Callable[[], bool]]) -> None:
+        """One pool's match cycles at the match interval, until the pool
+        leaves the active set, retire_resident halts it, or stop()."""
+        try:
+            while True:
+                # the sleep between ticks, named in a device trace
+                with obs.annotation("match.tick_wait"):
+                    if halt.wait(self.config.match_interval_s) \
+                            or self._stop.is_set():
+                        return
+                try:
+                    if lc is not None and not lc():
+                        continue
+                    if not self._match_gate():
+                        continue
+                    if all(p.name != pool for p in self.active_pools()):
+                        return
+                    self.match_cycle(pool)
+                except Exception:
+                    log.exception("scheduler cycle failed")
+        finally:
+            with self._match_loops_lock:
+                # a halted entry is retire_resident's to remove
+                if not halt.is_set():
+                    del self._match_loops[pool]
+                _count_loops(self._match_loops)
+
     def stop(self) -> None:
         if self._stop.is_set():
             return
         self._stop.set()
-        if getattr(self, "_consume_shards", None) is not None:
+        with self._match_loops_lock:
+            loops = list(self._match_loops.values())
+        for _t, halt in loops:
+            halt.set()
+        for t, _halt in loops:
+            if t is not None:
+                t.join(timeout=10)
+        resident = list(getattr(self, "_resident", {}).values())
+        if any(not rp.synchronous for rp in resident):
             self.drain_resident()
-            self._consume_shards.stop()
-        for rp in list(getattr(self, "_resident", {}).values()):
-            q = getattr(rp, "_launch_q", None)
-            if q is not None:
-                q.put(None)
+        for rp in resident:
+            for name in ("_launch_q", "_consume_q"):
+                q = getattr(rp, name, None)
+                if q is not None:
+                    q.put(None)
         for t in self._threads:
             t.join(timeout=2)
         # drain queued status updates before the workers die: a dropped
@@ -2604,6 +2699,13 @@ class Coordinator:
         if self.status_shards is not None:
             self.status_shards.stop()
         self._launch_pool.shutdown(wait=True)
+
+
+def _count_loops(loops: dict) -> None:
+    """The `match_loops` gauge: running per-pool match loops."""
+    metrics_registry.gauge("match_loops").set(
+        sum(1 for t, _h in loops.values()
+            if t is not None and t.is_alive()))
 
 
 def _failure_reason_names(job: Job) -> list[str]:

@@ -476,6 +476,7 @@ class ResidentPool:
             for o in cluster.pending_offers(pool):
                 offers.append(o)
                 self.offer_cluster[o.hostname] = cluster.name
+        held = self._held_tasks()
         self._host_gens = gens
         self.host_names = [o.hostname for o in offers]
         self.host_ids = {h: i for i, h in enumerate(self.host_names)}
@@ -583,7 +584,7 @@ class ResidentPool:
         self.Pcap, self.Rcap = Pcap, Rcap
         while True:
             try:
-                self._init_and_fill_mirrors(pending, run_insts, H)
+                self._init_and_fill_mirrors(pending, run_insts, H, held)
                 break
             except _NeedResync as e:
                 # sparse-slot demand exceeded a fixed cap during the
@@ -633,9 +634,31 @@ class ResidentPool:
         self._host_credit: dict[int, list] = {}
         self._last_resv: dict[str, str] = dict(co.reservations)
 
-    def _init_and_fill_mirrors(self, pending, run_insts, H: int) -> None:
+    def _held_tasks(self) -> set:
+        """The task ids the backends hold, read after their offers (the
+        set reconcile() also trusts)."""
+        held: set = set()
+        for cluster in self.coord.clusters.all():
+            held |= cluster.known_task_ids()
+        return held
+
+    def _consumption(self, inst, job, held: set) -> tuple:
+        """The consumption record of a running task whose host lane was
+        just read from the backend's offers. A task the store still
+        counts as running that no backend holds (`held`, read after the
+        offers) has ended there with its status not yet applied: the
+        offers already count its capacity free, so its record names no
+        host and its terminal credits nothing."""
+        hid = self.host_ids.get(inst.hostname, -1) \
+            if inst.task_id in held else -1
+        return (hid, self.coord._effective_mem(job), job.cpus, job.gpus,
+                1, job.ports)
+
+    def _init_and_fill_mirrors(self, pending, run_insts, H: int,
+                               held: set) -> None:
         """Allocate fresh host mirrors at the current caps and fill
-        them from the store (the retried section of a rebuild)."""
+        them from the store (the retried section of a rebuild); `held`
+        as `_consumption`."""
         Pcap, Rcap = self.Pcap, self.Rcap
         # dirty tracking must exist before the fill loops run (they mark
         # sparse slots dirty); reset again after the wholesale upload
@@ -682,10 +705,8 @@ class ResidentPool:
             self._alloc_pend(job)
         for inst, job in run_insts:
             self._alloc_run(inst, job)
-            hid = self.host_ids.get(inst.hostname, -1)
-            self._consumed_res[inst.task_id] = (
-                hid, self.coord._effective_mem(job), job.cpus, job.gpus,
-                1, job.ports)
+            self._consumed_res[inst.task_id] = self._consumption(
+                inst, job, held)
         self._flush_fill_batch()
         self._flush_run_batch()
 
@@ -1769,12 +1790,15 @@ class ResidentPool:
         missed launch/terminal — and the consumption records rebuild
         wholesale from current truth (every currently-running task is
         excluded from the drained offers, so its future terminal
-        credit is exact against the fresh lanes).
+        credit is exact against the fresh lanes; the backends' tasks,
+        read after those offers, name the ones that already ended
+        there: see `_consumption`).
 
         The role of the reference's reconciliation pass, kept off the
         per-cycle match path (scheduler.clj:1041-1104)."""
         co, pool = self.coord, self.pool
         store = co.store
+        held = self._held_tasks() if rebase else None
         with self.mirror_lock:
             # store truth and the event queue snapshot pair through
             # snapshot_view: the store emits events inside the same
@@ -1865,9 +1889,7 @@ class ResidentPool:
                 # (truth-driven — later event replays are guarded by
                 # the _consumed_res membership checks)
                 self._consumed_res = {
-                    tid: (self.host_ids.get(inst.hostname, -1),
-                          co._effective_mem(job), job.cpus, job.gpus,
-                          1, job.ports)
+                    tid: self._consumption(inst, job, held)
                     for tid, (inst, job) in run_truth.items()}
             self._flush_fill_batch()
             self._flush_run_batch()

@@ -306,6 +306,35 @@ def test_resident_async_consumer():
     coord.stop()
 
 
+def test_rebuild_gives_no_credit_for_a_task_the_backend_already_ended():
+    """A task that ended at the backend, with its status not yet applied
+    to the store, is free capacity in the offers a full rebuild reads
+    but still running in the store: its terminal, applied after the
+    rebuild, must not credit the host a second time (two jobs would then
+    be matched onto room for one, and the backend fail one of them)."""
+    store, cluster, coord = build(n_hosts=1)
+    coord.enable_resident()
+    a = mkjob(mem=100, cpus=16)
+    store.create_jobs([a])
+    assert coord.match_cycle().matched == 1
+    held = []
+    cluster.set_status_callback(
+        lambda *args, **kw: held.append((args, kw)))
+    assert cluster.advance(100.0) == 1     # ended at the backend only
+    assert a.state == JobState.RUNNING
+    coord._resident["default"].resync()
+    for args, kw in held:                  # the status lands late
+        coord._status_entry(*args, **kw)
+    cluster.set_status_callback(coord._status_entry)
+    assert a.state == JobState.COMPLETED
+    b, c = mkjob(mem=100, cpus=16), mkjob(mem=100, cpus=16)
+    store.create_jobs([b, c])
+    assert coord.match_cycle().matched == 1
+    insts = [i for j in (b, c) for i in j.instances]
+    assert len(insts) == 1 and insts[0].status == InstanceStatus.RUNNING
+    assert_state_matches_rebuild(coord)
+
+
 def test_retention_gc_between_resident_cycles():
     """The retention loop (r5) retires completed jobs while the
     resident path cycles: retire events are invisible to the mirrors
